@@ -35,6 +35,7 @@ from benchmarks import paper_tables as PT
 from repro.configs import get_config
 from repro.core.hardware import BITFUSION, SILAGO
 from repro.core.mohaq import MOHAQProblem
+from repro.launch.compile_cache import use_repo_compile_cache
 from repro.models.sru import LAYER_NAMES
 
 FIXED_OPS = 88000 + 10704
@@ -187,13 +188,14 @@ def kernel_quant_matmul():
     from repro.kernels import ops
     x = jax.random.normal(jax.random.PRNGKey(0), (128, 512), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (512, 256), jnp.float32)
+    interp = jax.default_backend() == "cpu"
     for bits in (8, 4, 2):
         packed, scales = ops.pack_for_kernel(w, bits, clip=2.0)
         first, us = _timeit(lambda: jax.block_until_ready(
-            ops.quant_matmul(x, packed, scales, bits, interpret=True)))
+            ops.quant_matmul(x, packed, scales, bits, interpret=None)))
         flops = 2 * 128 * 512 * 256
         emit(f"kernel_quant_matmul_int{bits}", us,
-             f"interpret_gflops={flops/us/1e3:.2f};"
+             f"gflops={flops/us/1e3:.2f};interpret_mode={interp};"
              f"container_bytes={packed.size};"
              f"ratio_vs_bf16={512*256*2/packed.size:.1f}x",
              us_first_call=first)
@@ -207,24 +209,23 @@ def kernel_sru_scan():
     v = jnp.ones(n) * 0.1
     z = jnp.zeros(n)
     first, us = _timeit(lambda: jax.block_until_ready(
-        ops.sru_scan(uw, uf, ur, v, v, z, z, interpret=True)))
-    emit("kernel_sru_scan", us, f"B={B};T={T};n={n};interpret_mode=True",
+        ops.sru_scan(uw, uf, ur, v, v, z, z, interpret=None)))
+    emit("kernel_sru_scan", us, f"B={B};T={T};n={n};"
+         f"interpret_mode={jax.default_backend() == 'cpu'}",
          us_first_call=first)
 
 
-_SHARDED_SCRIPT = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import dataclasses, json, time
-    import numpy as np
-    import jax, jax.numpy as jnp
+def _sharded_rows(steps: int, trials: int):
+    """Sharded vs single-device evaluator on a "pop" mesh over every
+    device this process sees. Parity is asserted here (integer error
+    counts, exact ==)."""
+    import dataclasses
     from repro.core import api
     from repro.core import sru_experiment as X
     from repro.data import synthetic
     from repro.launch.mesh import make_population_mesh
 
-    STEPS, TRIALS = %d, %d
-    trained = X.train_small_sru(steps=STEPS)
+    trained = X.train_small_sru(steps=steps)
     raw, _ = synthetic.speech_eval_sets(trained.task, batch=1, seq=24)
     stack = lambda bs: (
         jnp.concatenate([x["feats"] for x in bs])[:1, :24],
@@ -244,7 +245,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
         shard = compact.val_error_batch(allocs, mesh=mesh)
         assert shard == ref, "sharded evaluator diverged from v2"
         t1, t2 = [], []
-        for _ in range(TRIALS):
+        for _ in range(trials):
             t0 = time.perf_counter()
             compact.val_error_batch(allocs)
             t1.append(time.perf_counter() - t0)
@@ -252,44 +253,57 @@ _SHARDED_SCRIPT = textwrap.dedent("""
             compact.val_error_batch(allocs, mesh=mesh)
             t2.append(time.perf_counter() - t0)
         rows.append({"pop": pop, "n_devices": len(jax.devices()),
+                     "platform": jax.devices()[0].platform,
                      "v2_single_ms": med(t1) * 1e3,
                      "sharded_ms": med(t2) * 1e3,
                      "speedup_sharded_vs_v2": med(t1) / med(t2),
                      "bit_identical": True})
-    print("RESULT " + json.dumps(rows))
+    return rows
+
+
+# CPU rehearsal only: the virtual-device flag must precede jax's start-up,
+# so the 8-device host mesh needs a fresh interpreter
+_CPU_MESH_CHILD = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import json
+    from benchmarks.run import _sharded_rows
+    print("RESULT " + json.dumps(_sharded_rows(%d, %d)))
 """)
 
 
 def search_sharded(quick: bool = False):
     """``search_sharded`` row family: the mesh-partitioned population
-    evaluator vs the single-device v2 evaluator, on an 8-way host-device
-    mesh in a subprocess (XLA device-count flags must precede jax init).
-    Parity is asserted inside the subprocess (integer error counts,
-    exact ==). Naming follows the other rows: ``speedup_sharded_vs_v2`` =
-    t_v2_single / t_sharded, so values BELOW 1x mean the mesh path is
-    slower. On this CPU container the 8 "devices" share the same cores, so
-    sub-1x is expected — the row tracks that partitioning overhead and
-    keeps the mesh path exercised; on real accelerators the same path
-    scales candidates across chips."""
-    script = _SHARDED_SCRIPT % ((20, 2) if quick else (40, 5))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = subprocess.run([sys.executable, "-c", script], env=env,
-                         capture_output=True, text=True, timeout=900,
-                         cwd=root)
-    if out.returncode != 0:
-        raise RuntimeError("search_sharded subprocess failed:\n"
-                           + out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith("RESULT ")][-1]
-    rows = json.loads(line[len("RESULT "):])
+    evaluator vs the single-device v2 evaluator. On an accelerator it runs
+    in this process over ``jax.devices()`` (a child could not reach a chip
+    this process holds). On the CPU it rehearses on an 8-way host-device
+    mesh in a subprocess. Naming follows the other rows:
+    ``speedup_sharded_vs_v2`` = t_v2_single / t_sharded, so values BELOW 1x
+    mean the mesh path is slower — expected on the CPU rehearsal, where the
+    8 "devices" share the same cores."""
+    steps, trials = (20, 2) if quick else (40, 5)
+    if jax.default_backend() != "cpu":
+        rows = _sharded_rows(steps, trials)
+    else:
+        env = dict(os.environ)
+        env["PYTHONPATH"] = "src"
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        out = subprocess.run(
+            [sys.executable, "-c", _CPU_MESH_CHILD % (steps, trials)],
+            env=env, capture_output=True, text=True, timeout=900, cwd=root)
+        if out.returncode != 0:
+            raise RuntimeError("search_sharded subprocess failed:\n"
+                               + out.stderr[-2000:])
+        line = [l for l in out.stdout.splitlines()
+                if l.startswith("RESULT ")][-1]
+        rows = json.loads(line[len("RESULT "):])
     for r in rows:
         emit(f"search_sharded_p{r['pop']}",
              r["sharded_ms"] * 1e3 / r["pop"],
              f"n_devices={r['n_devices']};"
              f"speedup_sharded_vs_v2={r['speedup_sharded_vs_v2']:.2f}x;"
-             f"bit_identical={r['bit_identical']};host_mesh=cpu")
+             f"bit_identical={r['bit_identical']};"
+             f"platform={r['platform']}")
     return rows
 
 
@@ -1156,6 +1170,7 @@ def main() -> None:
                          "re-record the reference after box-state drift; "
                          "see the gate comment in search_pipeline_v2")
     args, _ = ap.parse_known_args()
+    use_repo_compile_cache()
     print("name,us_per_call,derived,us_first_call")
     table1_ops()
     table2_silago()

@@ -20,9 +20,11 @@ import time
 
 from repro.core import sru_experiment as X
 from repro.core.api import SearchSession, get_platform
+from repro.launch.compile_cache import use_repo_compile_cache
 
 
 def main():
+    use_repo_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="fewer generations / training steps")

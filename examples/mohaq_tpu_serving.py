@@ -54,6 +54,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.hardware import TPU_HBM_BW
 from repro.core.nsga2 import NSGA2
+from repro.launch.compile_cache import use_repo_compile_cache
 
 # relative MSE of b-bit symmetric quantization of a unit normal (numeric)
 QNOISE = {2: 0.119, 4: 0.0104, 8: 5.0e-5, 16: 1e-9}
@@ -257,6 +258,7 @@ if __name__ == "__main__":
                     help="run the checkpointed-search -> packed-artifact "
                          "-> SLO-routed serving demo (repro.serving)")
     args = ap.parse_args()
+    use_repo_compile_cache()
     if args.serve_demo:
         serve_demo()
     elif args.sharded_demo:

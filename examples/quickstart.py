@@ -13,10 +13,12 @@ from repro.configs import get_config
 from repro.core import sru_experiment as X
 from repro.core.hardware import BITFUSION, SILAGO
 from repro.core.mohaq import MOHAQProblem
+from repro.launch.compile_cache import use_repo_compile_cache
 from repro.models.sru import LAYER_NAMES
 
 
 def main():
+    use_repo_compile_cache()
     print("== 1. paper model breakdown (Table 4) ==")
     paper = get_config("sru_timit")
     counts = paper.layer_weight_counts()

@@ -5,7 +5,8 @@ deployment artifact.
 
 Demonstrates the TPU adaptation of the paper (DESIGN.md): int4/int2 weights
 packed in int8 containers, dequantized in-kernel. On this CPU container the
-kernel runs in interpret mode; on TPU the same call compiles to MXU ops.
+kernel runs in interpret mode; on TPU the same call compiles to MXU ops
+(``kernels.ops`` resolves the mode from the backend).
 The population-serving half goes through ``tools/convert_checkpoint.py``
 and the ``repro.serving`` tier: a trained search model + its chosen
 allocations are frozen into a packed artifact (int codes + scales +
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core.quantization import mmse_clip
 from repro.kernels import ops as kops
+from repro.launch.compile_cache import use_repo_compile_cache
 from repro.models import transformer as tfm
 
 
@@ -44,6 +46,7 @@ def decode_loop(params, cfg, tokens, gen, head_fn=None):
 
 
 def main():
+    use_repo_compile_cache()
     cfg = get_config("stablelm-1.6b").reduced()
     from repro.models.registry import get_model
     model = get_model(cfg)
@@ -65,7 +68,7 @@ def main():
 
     def quant_head(hidden):                            # (B, 1, D) -> logits
         h2 = hidden.reshape(-1, cfg.d_model).astype(jnp.float32)
-        y = kops.quant_matmul(h2, packed8, scales8, 8, interpret=True)
+        y = kops.quant_matmul(h2, packed8, scales8, 8)
         return y.reshape(hidden.shape[:-1] + (w.shape[1],))
 
     # --- serve: prefill a prompt, decode 8 tokens greedily, both heads ---
@@ -87,7 +90,7 @@ def main():
     # --- validate the kernel path against the dense head on real hiddens ---
     x = jax.random.normal(jax.random.PRNGKey(2), (B, cfg.d_model), jnp.float32)
     dense_logits = x @ w
-    kern_logits = kops.quant_matmul(x, packed4, scales4, 4, interpret=True)
+    kern_logits = kops.quant_matmul(x, packed4, scales4, 4)
     err = float(jnp.max(jnp.abs(dense_logits - kern_logits)))
     rel = err / float(jnp.max(jnp.abs(dense_logits)))
     print(f"int4 kernel vs dense head: max abs err {err:.3f} (rel {rel:.3f}) "

@@ -11,11 +11,13 @@ import tempfile
 import jax
 
 from repro.core import sru_experiment as X
+from repro.launch.compile_cache import use_repo_compile_cache
 from repro.models.sru import LAYER_NAMES
 from repro.training import checkpoint as ckpt
 
 
 def main():
+    use_repo_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=400)
     ap.add_argument("--ckpt-dir", default="")

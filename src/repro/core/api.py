@@ -139,12 +139,20 @@ def _resolve(platform: Union[str, HardwareModel]) -> HardwareModel:
     return get_platform(platform) if isinstance(platform, str) else platform
 
 
+def bank_format_kw(bank_format: str) -> Dict[str, str]:
+    """``val_error_batch`` keywords for a bank format. The default f32
+    format passes nothing, so targets without packed banks (the xLSTM)
+    keep working; any other format is passed through for the target to
+    honour or refuse."""
+    return {} if bank_format == "f32" else {"bank_format": bank_format}
+
+
 def build_problem_from_target(
         target: SearchTarget, platform: Union[str, HardwareModel],
         objectives: Sequence[str], *,
         sram_override: Optional[int] = None, batched: bool = True,
         mesh=None, partition: str = "shard_map",
-        share_memo: bool = True) -> MOHAQProblem:
+        share_memo: bool = True, bank_format: str = "f32") -> MOHAQProblem:
     """Construct a ``MOHAQProblem`` from any ``SearchTarget`` — the
     protocol-generic replacement for ``sru_experiment.build_problem``.
 
@@ -152,8 +160,11 @@ def build_problem_from_target(
     evaluation across devices; ``share_memo`` keeps the target's
     cross-search base-params error memo attached (platform sweeps score
     each allocation once — beacon searches re-point it, see
-    ``BeaconSearch.attach``)."""
+    ``BeaconSearch.attach``); ``bank_format`` picks the quantized-weight
+    bank format of the banked dispatch (``"f32"`` or ``"packed"``; error
+    counts are identical, see ``PopulationEvaluator``)."""
     hw = _resolve(platform)
+    batch_kw = bank_format_kw(bank_format)
     if sram_override is not None:
         hw = dataclasses.replace(hw, sram_bytes=sram_override)
 
@@ -161,7 +172,8 @@ def build_problem_from_target(
         return target.val_error(alloc)
 
     def batch_error_fn(allocs):
-        return target.val_error_batch(allocs, mesh=mesh, partition=partition)
+        return target.val_error_batch(allocs, mesh=mesh, partition=partition,
+                                      **batch_kw)
 
     return MOHAQProblem(
         layer_names=list(target.layer_names),
@@ -224,9 +236,11 @@ class SearchSession:
     ``platform`` is a registry name (``get_platform``) or a
     ``HardwareModel``; ``mesh``/``partition`` shard every population
     evaluation (scalar fallbacks unchanged); ``batched=False`` forces the
-    per-candidate path (bit-identical fronts). Each ``run`` builds a fresh
-    problem but shares the target's cross-search error memo, so
-    multi-platform sweeps over one target score each allocation once."""
+    per-candidate path (bit-identical fronts); ``bank_format="packed"``
+    scores populations from packed-integer banks instead of f32 stacks
+    (identical error counts). Each ``run`` builds a fresh problem but
+    shares the target's cross-search error memo, so multi-platform sweeps
+    over one target score each allocation once."""
     target: Any
     platform: Union[str, HardwareModel]
     objectives: Sequence[str] = ("error", "speedup", "energy")
@@ -235,6 +249,7 @@ class SearchSession:
     mesh: Any = None
     partition: str = "shard_map"
     share_memo: bool = True
+    bank_format: str = "f32"
 
     def __post_init__(self):
         self.platform = _resolve(self.platform)
@@ -244,7 +259,7 @@ class SearchSession:
             self.target, self.platform, self.objectives,
             sram_override=self.sram_override, batched=self.batched,
             mesh=self.mesh, partition=self.partition,
-            share_memo=self.share_memo)
+            share_memo=self.share_memo, bank_format=self.bank_format)
 
     def run(self, generations: int = 15, pop: int = 10, initial: int = 24,
             seed: int = 0, *, beacons: bool = False, retrain_steps: int = 60,
@@ -303,7 +318,7 @@ class SearchSession:
             bs = BeaconSearch.from_target(
                 prob, self.target, retrain_steps=retrain_steps,
                 batched=self.batched, mesh=self.mesh,
-                partition=self.partition,
+                partition=self.partition, bank_format=self.bank_format,
                 distance_threshold=distance_threshold,
                 skip_retrains=state.n_retrains if state is not None else 0)
             prob = bs.attach()

@@ -62,6 +62,7 @@ class BeaconSearch:
     def from_target(cls, problem: MOHAQProblem, target, *,
                     retrain_steps: int = 60, batched: bool = True,
                     mesh=None, partition: str = "shard_map",
+                    bank_format: str = "f32",
                     distance_threshold: float = 6.0,
                     skip_retrains: int = 0) -> "BeaconSearch":
         """Build the beacon wrapper from any ``SearchTarget`` (see
@@ -71,7 +72,8 @@ class BeaconSearch:
         the historical experiment wiring) and both error evaluators are
         the target's parameter-explicit paths. Beacon groups shard
         independently when a ``mesh`` is given: every grouped call is
-        itself a population partitioned over the mesh.
+        itself a population partitioned over the mesh; ``bank_format``
+        is the banked dispatch's bank format (``SearchSession``'s).
 
         ``skip_retrains`` fast-forwards the retraining data stream past
         the first N retrains (checkpoint resume: the restored beacons
@@ -81,9 +83,12 @@ class BeaconSearch:
         def error_with_params(params, alloc):
             return target.val_error(alloc, params=params)
 
+        from repro.core.api import bank_format_kw
+        batch_kw = bank_format_kw(bank_format)
+
         def batch_error_with_params(params, allocs):
             return target.val_error_batch(allocs, params=params, mesh=mesh,
-                                          partition=partition)
+                                          partition=partition, **batch_kw)
 
         if skip_retrains:
             retrain_fn = target.beacon_retrainer(

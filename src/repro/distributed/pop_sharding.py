@@ -43,7 +43,6 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 POP_AXIS = "pop"
@@ -98,8 +97,9 @@ def shard_population(fn: Callable, mesh: Mesh, *, n_replicated: int,
     pop_axis_size(mesh, axis)          # validates the axis exists
     rep_specs = (P(),) * n_replicated
     if mode == "shard_map":
-        inner = shard_map(fn, mesh=mesh, in_specs=rep_specs + (P(axis),),
-                          out_specs=P(axis), check_rep=False)
+        inner = jax.shard_map(fn, mesh=mesh,
+                              in_specs=rep_specs + (P(axis),),
+                              out_specs=P(axis), check_vma=False)
         return jax.jit(inner)
     rep = NamedSharding(mesh, P())
     return jax.jit(fn, in_shardings=(rep,) * n_replicated

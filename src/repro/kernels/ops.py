@@ -135,7 +135,7 @@ def bank_qmm_pop(x, packed, idx, interpret=None):
     bm = 8 if M >= 8 else M
     bn = 128 if N >= 128 else _next_mult(N, 8)
     x_p, _ = _pad_to(x, bm, 1)
-    # the raw kernel gathers (1, bn) scale tiles, so it wants full
+    # the raw kernel takes (|menu|, bn) scale tiles, so it wants full
     # per-channel rows; the stored bank keeps a broadcastable (K, 1)
     # column for per-tensor grids — expand here, at trace time
     scale = packed["scale"]

@@ -23,25 +23,43 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _unpack_block(packed, bits: int):
-    """(bk*bits//8, bn) int8 container -> (bk, bn) int8 signed values."""
-    if bits == 8:
-        return packed
+def _unpack_block(packed, bits: int, out_ref=None):
+    """Unpack a (R, bn) int8 container of ``bits``-wide codes, in the
+    ``ref.unpack_weights`` row order: row b*per + j is slot j of byte b,
+    low bits first.
+
+    Each slot j is decoded on whole (R, bn) int32 tiles. Inside a kernel
+    (``out_ref`` given) slot j goes to rows j, j+per, ... of ``out_ref`` as
+    f32 codes by a row-strided store: Mosaic has no sublane interleave, so
+    the row order is built in VMEM instead of by a reshape. Without
+    ``out_ref`` the same slots interleave by reshape into a (R*per, bn)
+    int8 array (host-side checks use this form)."""
     per = 8 // bits
-    u = packed.astype(jnp.uint8)
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * bits)[None, :, None]
-    vals = (u[:, None, :] >> shifts) & ((1 << bits) - 1)
-    sign = (vals & (1 << (bits - 1))) != 0
-    signed = vals.astype(jnp.int8) - sign.astype(jnp.int8) * (1 << bits)
-    return signed.reshape(-1, packed.shape[-1])
+    rows = packed.shape[0]
+    u = packed.astype(jnp.int32) & 0xFF
+    slots = []
+    for j in range(per):
+        v = (u >> (bits * j)) & ((1 << bits) - 1)
+        slots.append(v - ((v >> (bits - 1)) & 1) * (1 << bits))  # sign-ext
+    if out_ref is None:
+        return jnp.stack(slots, axis=1).reshape(-1, packed.shape[-1]
+                                                ).astype(jnp.int8)
+    for j, v in enumerate(slots):
+        out_ref[pl.ds(j, rows, stride=per), :] = v.astype(jnp.float32)
 
 
-def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, *, bits: int):
+def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, *scratch, bits: int):
     k = pl.program_id(2)
-    w = _unpack_block(w_ref[...], bits).astype(jnp.float32)
-    w = w * s_ref[...][None, :].astype(jnp.float32)
+    if bits == 8:
+        w = w_ref[...].astype(jnp.float32)
+    else:
+        (w_scr,) = scratch
+        _unpack_block(w_ref[...], bits, w_scr)
+        w = w_scr[...]
+    w = w * s_ref[...]
     acc = jnp.dot(x_ref[...].astype(jnp.float32), w,
                   preferred_element_type=jnp.float32)
 
@@ -82,15 +100,18 @@ def quant_matmul(x, packed_w, scales, bits: int,
             f"(K*bits%8={(K * bits) % 8})")
     grid = (M // bm, N // bn, K // bk)
     kernel = functools.partial(_qmm_kernel, bits=bits)
+    # scales ride as a (1, N) row: a 1-D (bn,) block has no legal TPU layout
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // per, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        scratch_shapes=([] if bits == 8
+                        else [pltpu.VMEM((bk, bn), jnp.float32)]),
         interpret=interpret,
-    )(x, packed_w, scales)
+    )(x, packed_w, scales.reshape(1, N))

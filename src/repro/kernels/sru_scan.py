@@ -43,6 +43,12 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.quant_matmul import _unpack_block
 
 
+def _rows(*vecs):
+    """(n,) channel vectors as (1, n) rows: a 1-D block has no legal TPU
+    layout, a (1, bn) block of a (1, n) array does."""
+    return tuple(v.reshape(1, -1) for v in vecs)
+
+
 def _sru_kernel(uw_ref, uf_ref, ur_ref, vf_ref, vr_ref, bf_ref, br_ref,
                 h_ref, r_ref, cl_ref):
     T = uw_ref.shape[1]
@@ -53,16 +59,14 @@ def _sru_kernel(uw_ref, uf_ref, ur_ref, vf_ref, vr_ref, bf_ref, br_ref,
     c0 = jnp.zeros((uw_ref.shape[0], uw_ref.shape[2]), jnp.float32)
 
     def body(t, c):
-        uw_t = pl.load(uw_ref, (slice(None), pl.ds(t, 1), slice(None)))[:, 0]
-        uf_t = pl.load(uf_ref, (slice(None), pl.ds(t, 1), slice(None)))[:, 0]
-        ur_t = pl.load(ur_ref, (slice(None), pl.ds(t, 1), slice(None)))[:, 0]
+        uw_t = uw_ref[:, t, :]
+        uf_t = uf_ref[:, t, :]
+        ur_t = ur_ref[:, t, :]
         f = jax.nn.sigmoid(uf_t + vf * c + bf)
         r = jax.nn.sigmoid(ur_t + vr * c + br)
         c_new = f * c + (1.0 - f) * uw_t
-        pl.store(h_ref, (slice(None), pl.ds(t, 1), slice(None)),
-                 (r * c_new)[:, None])
-        pl.store(r_ref, (slice(None), pl.ds(t, 1), slice(None)),
-                 r[:, None])
+        h_ref[:, t, :] = r * c_new
+        r_ref[:, t, :] = r
         return c_new
 
     c_last = jax.lax.fori_loop(0, T, body, c0)
@@ -77,10 +81,13 @@ def sru_scan(uw, uf, ur, v_f, v_r, b_f, b_r,
     B and n must divide the block sizes (ops.sru_scan pads for you)."""
     B, T, n = uw.shape
     bb, bn = block
-    assert B % bb == 0 and n % bn == 0, (uw.shape, block)
+    if B % bb or n % bn:
+        raise ValueError(
+            f"sru_scan shapes must divide the block: u {uw.shape}, block "
+            f"(bb, bn)={block}; ops.sru_scan pads for you")
     grid = (B // bb, n // bn)
     stream = pl.BlockSpec((bb, T, bn), lambda i, j: (i, 0, j))
-    vec = pl.BlockSpec((bn,), lambda i, j: (j,))
+    vec = pl.BlockSpec((1, bn), lambda i, j: (0, j))
     return pl.pallas_call(
         _sru_kernel,
         grid=grid,
@@ -91,7 +98,7 @@ def sru_scan(uw, uf, ur, v_f, v_r, b_f, b_r,
                    jax.ShapeDtypeStruct((B, T, n), jnp.float32),
                    jax.ShapeDtypeStruct((B, n), jnp.float32)],
         interpret=interpret,
-    )(uw, uf, ur, v_f, v_r, b_f, b_r)
+    )(uw, uf, ur, *_rows(v_f, v_r, b_f, b_r))
 
 
 def _sru_kernel_pop(uw_ref, uf_ref, ur_ref, vf_ref, vr_ref, bf_ref, br_ref,
@@ -105,15 +112,14 @@ def _sru_kernel_pop(uw_ref, uf_ref, ur_ref, vf_ref, vr_ref, bf_ref, br_ref,
     c0 = jnp.zeros((uw_ref.shape[1], uw_ref.shape[3]), jnp.float32)
 
     def body(t, c):
-        idx = (slice(None), slice(None), pl.ds(t, 1), slice(None))
-        uw_t = pl.load(uw_ref, idx)[0, :, 0]
-        uf_t = pl.load(uf_ref, idx)[0, :, 0]
-        ur_t = pl.load(ur_ref, idx)[0, :, 0]
+        uw_t = uw_ref[0, :, t, :]
+        uf_t = uf_ref[0, :, t, :]
+        ur_t = ur_ref[0, :, t, :]
         f = jax.nn.sigmoid(uf_t + vf * c + bf)
         r = jax.nn.sigmoid(ur_t + vr * c + br)
         c_new = f * c + (1.0 - f) * uw_t
-        pl.store(h_ref, idx, (r * c_new)[None, :, None])
-        pl.store(r_ref, idx, r[None, :, None])
+        h_ref[0, :, t, :] = r * c_new
+        r_ref[0, :, t, :] = r
         return c_new
 
     c_last = jax.lax.fori_loop(0, T, body, c0)
@@ -144,9 +150,15 @@ def bank_mxv_pop(x, bank, idx, block: Tuple[int, int] = (8, 128),
     sizes (ops.bank_mxv_pop pads for you)."""
     P, M, m = x.shape
     K, m2, N = bank.shape
-    assert m == m2 and idx.shape == (P,), (x.shape, bank.shape, idx.shape)
+    if m != m2 or idx.shape != (P,):
+        raise ValueError(
+            f"bank_mxv_pop operand mismatch: x {x.shape}, bank "
+            f"{bank.shape}, idx {idx.shape}")
     bm, bn = block
-    assert M % bm == 0 and N % bn == 0, (x.shape, block)
+    if M % bm or N % bn:
+        raise ValueError(
+            f"bank_mxv_pop shapes must divide the block: x {x.shape}, "
+            f"N={N}, block (bm, bn)={block}; ops.bank_mxv_pop pads for you")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(P, M // bm, N // bn),
@@ -166,21 +178,24 @@ def bank_mxv_pop(x, bank, idx, block: Tuple[int, int] = (8, 128),
 
 
 def _bank_qmm_kernel(idx_ref, x_ref, q2_ref, q4_ref, q8_ref, q16_ref,
-                     s_ref, o_ref):
-    # the scale-row gather happened in s_ref's index_map; the containers are
-    # menu-independent, so the body unpacks each (same _unpack_block as
-    # quant_matmul) and selects the lane's grid by the prefetched menu index
-    m = q8_ref.shape[0]
+                     s_ref, o_ref, w_scr):
+    # the containers and the (|menu|, bn) scale tile are menu-independent
+    # blocks; only the lane's selected container is decoded (same
+    # _unpack_block as quant_matmul) and scaled by its menu row
+    m = x_ref.shape[2]
     sel = idx_ref[pl.program_id(0)]
-    w2 = _unpack_block(q2_ref[...], 2)[:m].astype(jnp.float32)
-    w4 = _unpack_block(q4_ref[...], 4)[:m].astype(jnp.float32)
-    w8 = q8_ref[...].astype(jnp.float32)
-    w16 = q16_ref[...].astype(jnp.float32)
-    codes = jnp.where(sel == 0, w2,
-                      jnp.where(sel == 1, w4,
-                                jnp.where(sel == 2, w8, w16)))
-    w = codes * s_ref[0][None, :].astype(jnp.float32)
-    o_ref[0] = jnp.dot(x_ref[0], w, preferred_element_type=jnp.float32)
+    for k, (ref, bits) in enumerate(((q2_ref, 2), (q4_ref, 4),
+                                     (q8_ref, 8), (q16_ref, 16))):
+        @pl.when(sel == k)
+        def _(ref=ref, bits=bits, k=k):
+            if bits in (2, 4):
+                _unpack_block(ref[...], bits, w_scr)
+                codes = w_scr[pl.ds(0, m), :]
+            else:
+                codes = ref[...].astype(jnp.float32)
+            w = codes * s_ref[k:k + 1, :]
+            o_ref[0] = jnp.dot(x_ref[0], w,
+                               preferred_element_type=jnp.float32)
 
 
 def bank_qmm_pop(x, packed, idx, block: Tuple[int, int] = (8, 128),
@@ -195,8 +210,9 @@ def bank_qmm_pop(x, packed, idx, block: Tuple[int, int] = (8, 128),
     menu indices ordered like ``SUPPORTED_BITS`` (0 -> 2-bit ... 3 -> 16-bit).
     Returns (P, M, N) f32 with ``out[p] = x[p] @ dequant(packed)[idx[p]]``.
 
-    Only the (1, bn)-tile of the *selected* scale row is gathered via the
-    scalar-prefetch index_map; the integer containers stream in at
+    The whole (|menu|, bn) scale tile rides along (a one-row block breaks
+    the TPU's (8, 128) tiling) and the kernel picks the lane's row and
+    container by the scalar-prefetched menu index; the containers stream in at
     ~3.75 bytes/weight total — less than the f32 bank lane's 4 bytes/weight
     for the gathered row — and dequantization runs on the VPU in-kernel.
     M and N must divide the block sizes (ops.bank_qmm_pop pads for you)."""
@@ -228,10 +244,13 @@ def bank_qmm_pop(x, packed, idx, block: Tuple[int, int] = (8, 128),
                                (0, j)),
                   pl.BlockSpec((m, bn), lambda p, i, j, idx_ref: (0, j)),
                   pl.BlockSpec((m, bn), lambda p, i, j, idx_ref: (0, j)),
-                  pl.BlockSpec((1, bn), lambda p, i, j, idx_ref:
-                               (idx_ref[p], j))],
+                  pl.BlockSpec((scale.shape[0], bn),
+                               lambda p, i, j, idx_ref: (0, j))],
         out_specs=pl.BlockSpec((1, bm, bn), lambda p, i, j, idx_ref:
                                (p, i, j)),
+        # decoded sub-byte codes: room for the longer of the two unpacks
+        scratch_shapes=[pltpu.VMEM(
+            (max(4 * q2.shape[0], 2 * q4.shape[0]), bn), jnp.float32)],
     )
     return pl.pallas_call(
         _bank_qmm_kernel,
@@ -254,10 +273,13 @@ def sru_scan_pop(uw, uf, ur, v_f, v_r, b_f, b_r,
     B and n must divide the block sizes (ops.sru_scan_pop pads for you)."""
     P, B, T, n = uw.shape
     bb, bn = block
-    assert B % bb == 0 and n % bn == 0, (uw.shape, block)
+    if B % bb or n % bn:
+        raise ValueError(
+            f"sru_scan_pop shapes must divide the block: u {uw.shape}, "
+            f"block (bb, bn)={block}; ops.sru_scan_pop pads for you")
     grid = (P, B // bb, n // bn)
     stream = pl.BlockSpec((1, bb, T, bn), lambda p, i, j: (p, i, 0, j))
-    vec = pl.BlockSpec((bn,), lambda p, i, j: (j,))
+    vec = pl.BlockSpec((1, bn), lambda p, i, j: (0, j))
     return pl.pallas_call(
         _sru_kernel_pop,
         grid=grid,
@@ -268,4 +290,4 @@ def sru_scan_pop(uw, uf, ur, v_f, v_r, b_f, b_r,
                    jax.ShapeDtypeStruct((P, B, T, n), jnp.float32),
                    jax.ShapeDtypeStruct((P, B, n), jnp.float32)],
         interpret=interpret,
-    )(uw, uf, ur, v_f, v_r, b_f, b_r)
+    )(uw, uf, ur, *_rows(v_f, v_r, b_f, b_r))

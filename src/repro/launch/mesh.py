@@ -10,12 +10,20 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the repo shards through
+    ``with_sharding_constraint`` and jit shardings, which only bind to Auto
+    axes (``make_mesh`` defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_for(n_devices: Optional[int] = None, model_parallel: int = 1,
@@ -23,8 +31,10 @@ def make_mesh_for(n_devices: Optional[int] = None, model_parallel: int = 1,
     """Elastic mesh: build the best (data, model) grid for whatever devices
     are alive — used by the trainer after restarts on fewer/more hosts."""
     n = n_devices or len(jax.devices())
-    assert n % model_parallel == 0, (n, model_parallel)
-    return jax.make_mesh((n // model_parallel, model_parallel), axes)
+    if n % model_parallel:
+        raise ValueError(f"{n} devices do not split into model_parallel="
+                         f"{model_parallel}")
+    return _auto_mesh((n // model_parallel, model_parallel), axes)
 
 
 def make_population_mesh(n_devices: Optional[int] = None, axis: str = "pop"):
@@ -35,4 +45,4 @@ def make_population_mesh(n_devices: Optional[int] = None, axis: str = "pop"):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to exercise it
     on a CPU host."""
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return _auto_mesh((n,), (axis,))

@@ -19,8 +19,9 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.base import ShapeConfig
     from repro.training import optimizer as opt, train_step as ts
     from repro.roofline.hlo_analysis import analyze_hlo
+    from repro.launch.mesh import make_mesh_for
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh_for(8, model_parallel=4)
     cfg = get_config("stablelm-1.6b").reduced()
     model = get_model(cfg)
     shape = ShapeConfig("t", 32, 4, "train")

@@ -220,6 +220,21 @@ class TestPackedEvaluator:
             allocs, bank_format="packed") == scalar
         assert trained.val_error_batch(allocs, use_banks=True) == scalar
 
+    @pytest.mark.parametrize("beacons", [False, True])
+    def test_search_session_packed_front_identical(self, trained, beacons):
+        """SearchSession(bank_format='packed') scores through the packed
+        banks (beacon groups too) and finds the f32 session's front."""
+        from repro.core.api import SearchSession
+        kw = dict(generations=2, pop=6, initial=8, seed=3, beacons=beacons,
+                  retrain_steps=4)
+        fronts = {}
+        for fmt in ("f32", "packed"):
+            sess = SearchSession(trained, "bitfusion", ("error", "speedup"),
+                                 share_memo=False, bank_format=fmt)
+            res = sess.run(**kw)
+            fronts[fmt] = res.front_key()
+        assert fronts["packed"] == fronts["f32"]
+
     def test_bank_format_validation(self, trained):
         common = dict(layer_names=list(trained.layer_names),
                       val_subsets=trained.val_subsets,

@@ -90,3 +90,51 @@ class TestRules:
         # and a fake 16-way mesh drops the indivisible dim (unit logic)
         m = FakeMesh(model=16)
         assert sh.fix_spec(m, P("model", None), (7, 3)) == P(None, None)
+
+
+class TestLaunchMeshes:
+    """The launch meshes bind to ``with_sharding_constraint``: every axis
+    is Auto (``jax.make_mesh`` alone builds Explicit axes, which refuse)."""
+
+    @pytest.mark.parametrize("build", ["population", "elastic"])
+    def test_axes_are_auto(self, build):
+        from jax.sharding import AxisType, NamedSharding
+        from repro.launch.mesh import make_mesh_for, make_population_mesh
+        mesh = (make_population_mesh() if build == "population"
+                else make_mesh_for(model_parallel=1))
+        assert set(mesh.axis_types) == {AxisType.Auto}
+        spec = NamedSharding(mesh, P(mesh.axis_names[0]))
+        out = jax.jit(lambda x: jax.lax.with_sharding_constraint(x, spec))(
+            jnp.arange(8.0))
+        assert out.shape == (8,)
+
+    def test_elastic_mesh_rejects_uneven_split(self):
+        from repro.launch.mesh import make_mesh_for
+        with pytest.raises(ValueError, match="model_parallel=3"):
+            make_mesh_for(4, model_parallel=3)
+
+
+class TestCompileCache:
+    @pytest.fixture
+    def restore_cache_dir(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_repo_dir_when_unset(self, monkeypatch, restore_cache_dir):
+        import os
+        from repro.launch import compile_cache as cc
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert cc.use_repo_compile_cache() == cc.REPO_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == cc.REPO_CACHE_DIR
+        assert os.path.basename(cc.REPO_CACHE_DIR) == ".jax_cache"
+        assert os.path.isfile(os.path.join(
+            os.path.dirname(cc.REPO_CACHE_DIR), "pyproject.toml"))
+
+    @pytest.mark.parametrize("value", ["/elsewhere/cache", ""])
+    def test_environment_wins(self, monkeypatch, restore_cache_dir, value):
+        from repro.launch import compile_cache as cc
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", value)
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert cc.use_repo_compile_cache() == value
+        assert jax.config.jax_compilation_cache_dir is None

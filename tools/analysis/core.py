@@ -174,7 +174,8 @@ class ModuleContext:
 
 def _is_jit_expr(ctx: ModuleContext, node: ast.AST) -> bool:
     target = ctx.resolve_call_target(node)
-    if target in ("jax.jit", "jax.experimental.shard_map.shard_map"):
+    if target in ("jax.jit", "jax.shard_map",
+                  "jax.experimental.shard_map.shard_map"):
         return True
     # plain attribute without an import resolution (e.g. jax.jit when jax
     # itself resolves) is covered above; fall back to a literal match

@@ -111,6 +111,7 @@ _ELEMENTWISE = frozenset("""
 # call-like primitives: params key holding the (closed) sub-jaxpr whose
 # invars map 1:1 onto the eqn's invars
 _CALL_JAXPR_PARAM = {
+    "jit": "jaxpr",             # named "pjit" before jax 0.7
     "pjit": "jaxpr",
     "closed_call": "call_jaxpr",
     "core_call": "call_jaxpr",
