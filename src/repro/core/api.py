@@ -139,14 +139,6 @@ def _resolve(platform: Union[str, HardwareModel]) -> HardwareModel:
     return get_platform(platform) if isinstance(platform, str) else platform
 
 
-def bank_format_kw(bank_format: str) -> Dict[str, str]:
-    """``val_error_batch`` keywords for a bank format. The default f32
-    format passes nothing, so targets without packed banks (the xLSTM)
-    keep working; any other format is passed through for the target to
-    honour or refuse."""
-    return {} if bank_format == "f32" else {"bank_format": bank_format}
-
-
 def build_problem_from_target(
         target: SearchTarget, platform: Union[str, HardwareModel],
         objectives: Sequence[str], *,
@@ -164,7 +156,6 @@ def build_problem_from_target(
     bank format of the banked dispatch (``"f32"`` or ``"packed"``; error
     counts are identical, see ``PopulationEvaluator``)."""
     hw = _resolve(platform)
-    batch_kw = bank_format_kw(bank_format)
     if sram_override is not None:
         hw = dataclasses.replace(hw, sram_bytes=sram_override)
 
@@ -173,7 +164,7 @@ def build_problem_from_target(
 
     def batch_error_fn(allocs):
         return target.val_error_batch(allocs, mesh=mesh, partition=partition,
-                                      **batch_kw)
+                                      bank_format=bank_format)
 
     return MOHAQProblem(
         layer_names=list(target.layer_names),

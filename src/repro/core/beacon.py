@@ -83,12 +83,10 @@ class BeaconSearch:
         def error_with_params(params, alloc):
             return target.val_error(alloc, params=params)
 
-        from repro.core.api import bank_format_kw
-        batch_kw = bank_format_kw(bank_format)
-
         def batch_error_with_params(params, allocs):
             return target.val_error_batch(allocs, params=params, mesh=mesh,
-                                          partition=partition, **batch_kw)
+                                          partition=partition,
+                                          bank_format=bank_format)
 
         if skip_retrains:
             retrain_fn = target.beacon_retrainer(

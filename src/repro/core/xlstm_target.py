@@ -341,9 +341,14 @@ class XLSTMTarget:
 
     def val_error_batch(self, allocs, params=None, *, mesh=None,
                         partition: str = "shard_map",
-                        use_banks: Optional[bool] = None) -> List[float]:
+                        use_banks: Optional[bool] = None,
+                        bank_format: str = "f32") -> List[float]:
         """Max-over-subsets next-token error % for every allocation in one
-        dispatch (generic evaluator: buckets, folding, banks, mesh)."""
+        dispatch (generic evaluator: buckets, folding, banks, mesh). Only
+        f32 banks exist for this target; another ``bank_format`` raises."""
+        if bank_format != "f32":
+            raise ValueError(f"the xLSTM target has f32 banks only, not "
+                             f"bank_format={bank_format!r}")
         params = self.params if params is None else params
         return self.batched_evaluator(mesh=mesh, partition=partition,
                                       use_banks=use_banks

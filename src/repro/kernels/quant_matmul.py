@@ -26,29 +26,41 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _unpack_block(packed, bits: int, out_ref=None):
-    """Unpack a (R, bn) int8 container of ``bits``-wide codes, in the
-    ``ref.unpack_weights`` row order: row b*per + j is slot j of byte b,
-    low bits first.
+def _unpack_block(packed, bits: int, out_ref):
+    """Unpack a (R, bn) int8 container of ``bits``-wide codes into
+    ``out_ref`` as f32 codes, in the ``ref.unpack_weights`` row order: row
+    b*per + j is slot j of byte b, low bits first.
 
-    Each slot j is decoded on whole (R, bn) int32 tiles. Inside a kernel
-    (``out_ref`` given) slot j goes to rows j, j+per, ... of ``out_ref`` as
-    f32 codes by a row-strided store: Mosaic has no sublane interleave, so
-    the row order is built in VMEM instead of by a reshape. Without
-    ``out_ref`` the same slots interleave by reshape into a (R*per, bn)
-    int8 array (host-side checks use this form)."""
+    Each slot j is decoded on whole (R, bn) int32 tiles and goes to rows
+    j, j+per, ... by a row-strided store: Mosaic has no sublane interleave,
+    so the row order is built in VMEM instead of by a reshape."""
     per = 8 // bits
     rows = packed.shape[0]
     u = packed.astype(jnp.int32) & 0xFF
-    slots = []
     for j in range(per):
         v = (u >> (bits * j)) & ((1 << bits) - 1)
-        slots.append(v - ((v >> (bits - 1)) & 1) * (1 << bits))  # sign-ext
-    if out_ref is None:
-        return jnp.stack(slots, axis=1).reshape(-1, packed.shape[-1]
-                                                ).astype(jnp.int8)
-    for j, v in enumerate(slots):
+        v = v - ((v >> (bits - 1)) & 1) * (1 << bits)            # sign-ext
         out_ref[pl.ds(j, rows, stride=per), :] = v.astype(jnp.float32)
+
+
+def unpack_codes(packed, bits: int, interpret: bool = False):
+    """Decode a whole (R, N) int8 container with the kernels' own
+    ``_unpack_block``, as one block: (R * 8 // bits, N) f32 codes. The
+    layout checks (analysis rule K4, the round-trip tests) run this, so
+    they check the decode the kernels run."""
+    def kernel(p_ref, o_ref):
+        _unpack_block(p_ref[...], bits, o_ref)
+
+    rows, n = packed.shape
+    out_rows = rows * (8 // bits)
+    return pl.pallas_call(
+        kernel,
+        grid=(1,),
+        in_specs=[pl.BlockSpec((rows, n), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((out_rows, n), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((out_rows, n), jnp.float32),
+        interpret=interpret,
+    )(packed)
 
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, *scratch, bits: int):

@@ -263,6 +263,19 @@ class TestXLSTMEndToEnd:
         requant = xlstm.val_error_batch(allocs, use_banks=False)
         assert banked == requant
 
+    def test_packed_bank_format_refused(self, xlstm):
+        """The xLSTM has f32 banks only: a session asking for packed banks
+        fails on its first batched evaluation instead of scoring f32."""
+        top = max(xlstm.menu)
+        alloc = {n: (top, top) for n in xlstm.layer_names}
+        with pytest.raises(ValueError, match="f32 banks only"):
+            xlstm.val_error_batch([alloc], bank_format="packed")
+        prob = api.build_problem_from_target(xlstm, BITFUSION,
+                                             ("error", "speedup"),
+                                             bank_format="packed")
+        with pytest.raises(ValueError, match="f32 banks only"):
+            prob.batch_error_fn([alloc])
+
     def test_retrain_deterministic_and_effective(self, xlstm):
         """Binary-connect QAT for the xLSTM: the retrainer's data stream
         is seeded, so two retrains of the same alloc are bit-identical;

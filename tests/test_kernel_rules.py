@@ -15,8 +15,8 @@ from jax.experimental import pallas as pl
 
 from tools.analysis import kernel_rules as kr
 
-KERNEL_FUNCS = {"quant_matmul", "sru_scan", "sru_scan_pop",
-                "bank_mxv_pop", "bank_qmm_pop"}
+KERNEL_FUNCS = {"quant_matmul", "unpack_codes", "sru_scan",
+                "sru_scan_pop", "bank_mxv_pop", "bank_qmm_pop"}
 
 
 @pytest.fixture(scope="module")

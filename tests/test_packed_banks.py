@@ -22,7 +22,7 @@ from repro.core import quantization as Q
 from repro.core import sru_experiment as X
 from repro.kernels import ops
 from repro.kernels import ref as kref
-from repro.kernels.quant_matmul import _unpack_block
+from repro.kernels.quant_matmul import unpack_codes
 from repro.models import sru
 from tools import convert_checkpoint as CC
 
@@ -124,8 +124,8 @@ class TestUnpackRoundTrip:
     def test_unpack_block_roundtrips_ref_packing(self, seed, bits):
         """Property: for any codes in the bits-range (most-negative code
         forced present), ``ref.pack_weights`` then the kernel-side
-        ``_unpack_block`` recovers them exactly — and agrees with
-        ``ref.unpack_weights``."""
+        ``_unpack_block`` (run in a Pallas call) recovers them exactly —
+        and agrees with ``ref.unpack_weights``."""
         rng = np.random.default_rng(seed)
         lo, hi = Q.INT_RANGES[bits]
         K = int(rng.integers(1, 6)) * (8 // bits)
@@ -133,7 +133,8 @@ class TestUnpackRoundTrip:
         codes = rng.integers(lo, hi + 1, (K, N)).astype(np.int8)
         codes[rng.integers(0, K), rng.integers(0, N)] = lo  # most-negative
         packed = kref.pack_weights(jnp.asarray(codes), bits)
-        via_block = np.asarray(_unpack_block(packed, bits))[:K]
+        via_block = np.asarray(
+            unpack_codes(packed, bits, interpret=True))[:K]
         via_ref = np.asarray(kref.unpack_weights(packed, bits, K))
         assert np.array_equal(via_block, codes)
         assert np.array_equal(via_ref, codes)

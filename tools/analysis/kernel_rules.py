@@ -58,6 +58,7 @@ from __future__ import annotations
 import ast
 import contextlib
 import dataclasses
+import functools
 import itertools
 import os
 import traceback
@@ -209,6 +210,7 @@ def default_drivers() -> List[Tuple[str, Callable[[], None]]]:
     import numpy as np
     import jax.numpy as jnp
     from repro.kernels import ops
+    from repro.kernels import quant_matmul as kqmm
     from repro.core import quantization as Q
 
     rng = np.random.default_rng(0)
@@ -250,6 +252,16 @@ def default_drivers() -> List[Tuple[str, Callable[[], None]]]:
         x = f32(4, 8, 512)
         idx = jnp.asarray([0, 1, 2, 3], jnp.int32)
         ops.bank_qmm_pop.__wrapped__(x, packed, idx, interpret=True)
+
+    def unpack_codes_driver(bits: int) -> Callable[[], None]:
+        def run() -> None:
+            packed, _ = ops.pack_for_kernel(f32(256, 128), bits, 3.0)
+            kqmm.unpack_codes(packed, bits, interpret=True)
+        return run
+
+    for bits in (2, 4):
+        drivers.append((f"unpack_codes[bits={bits}]",
+                        unpack_codes_driver(bits)))
 
     drivers += [("sru_scan", sru_scan_driver),
                 ("sru_scan_pop", sru_scan_pop_driver),
@@ -400,8 +412,9 @@ def _unpack_def_line() -> Tuple[str, int]:
 def check_k4(pack_fn: Optional[Callable] = None,
              unpack_fn: Optional[Callable] = None) -> List[Finding]:
     """Producer/consumer packed-layout agreement. ``pack_fn``/``unpack_fn``
-    default to the real ``ref.pack_weights`` / ``quant_matmul._unpack_block``
-    — tests inject broken ones to prove the detector is live."""
+    default to the real ``ref.pack_weights`` / the kernels' decode
+    (``quant_matmul._unpack_block``, run through ``unpack_codes``) — tests
+    inject broken ones to prove the detector is live."""
     import numpy as np
     import jax.numpy as jnp
     from repro.kernels import ref as kref
@@ -409,7 +422,8 @@ def check_k4(pack_fn: Optional[Callable] = None,
     from repro.core import quantization as Q
 
     pack = pack_fn or kref.pack_weights
-    unpack = unpack_fn or kqmm._unpack_block
+    unpack = unpack_fn or functools.partial(kqmm.unpack_codes,
+                                            interpret=True)
     path, line = _unpack_def_line()
     out: List[Finding] = []
     rng = np.random.default_rng(7)
