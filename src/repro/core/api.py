@@ -85,6 +85,8 @@ from dataclasses import dataclass
 from typing import (Any, Callable, Dict, List, Optional, Protocol, Sequence,
                     Tuple, Union, runtime_checkable)
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.beacon import BeaconSearch
 from repro.core.hardware import HardwareModel, get_platform, list_platforms
 from repro.core.mohaq import Alloc, MOHAQProblem, MOHAQResult, run_search
@@ -271,7 +273,13 @@ class SearchSession:
         (target, platform, menu, seed) + settings and continues — the
         resumed final Pareto front is bit-identical to the uninterrupted
         run (the GA's SeedSequence spawn-index discipline, not a re-seed,
-        makes this exact)."""
+        makes this exact).
+
+        Tracing: problem construction and the search are one profiler span,
+        ``search.run`` (stat ``seed``), the root of the spans the GA,
+        ``MOHAQProblem.evaluate_population`` and ``PopulationEvaluator``
+        record while a ``jax.profiler`` trace is active; ``build_problem``
+        inside it is ``search.build``."""
         from repro.core import checkpointing as ckpt
 
         if resume and checkpoint_dir is None:
@@ -297,50 +305,55 @@ class SearchSession:
                     log(f"resumed from checkpoint: {state.next_gen} "
                         f"generation(s) done, {len(state.history)} evals, "
                         f"{state.n_retrains} retrains")
-        prob = self.build_problem()
-        bs = None
-        if beacons:
-            if not getattr(self.target, "supports_retrain",
-                           hasattr(self.target, "beacon_retrainer")):
-                raise NotImplementedError(
-                    f"target {type(self.target).__name__} does not support "
-                    "beacon retraining (supports_retrain is falsy); run "
-                    "with beacons=False")
-            bs = BeaconSearch.from_target(
-                prob, self.target, retrain_steps=retrain_steps,
-                batched=self.batched, mesh=self.mesh,
-                partition=self.partition, bank_format=self.bank_format,
-                distance_threshold=distance_threshold,
-                skip_retrains=state.n_retrains if state is not None else 0)
-            prob = bs.attach()
-        resume_state = None
-        if state is not None:
-            ckpt.restore_into(state, prob, bs)
-            resume_state = state.ga_resume()
-        on_generation = saver = None
-        if store is not None:
-            final_prob, final_bs = prob, bs
-            # persistence overlaps the next generation's compute: capture
-            # copies only the new history suffix on this thread, the
-            # incremental encode + durable write happen on the saver's
-            # worker (FIFO-ordered, drained before run returns)
-            saver = ckpt.AsyncSaver(store, key, settings)
+        with TraceAnnotation("search.run", seed=int(seed)):
+            with TraceAnnotation("search.build"):
+                prob = self.build_problem()
+            bs = None
+            if beacons:
+                if not getattr(self.target, "supports_retrain",
+                               hasattr(self.target, "beacon_retrainer")):
+                    raise NotImplementedError(
+                        f"target {type(self.target).__name__} does not "
+                        "support beacon retraining (supports_retrain is "
+                        "falsy); run with beacons=False")
+                bs = BeaconSearch.from_target(
+                    prob, self.target, retrain_steps=retrain_steps,
+                    batched=self.batched, mesh=self.mesh,
+                    partition=self.partition, bank_format=self.bank_format,
+                    distance_threshold=distance_threshold,
+                    skip_retrains=(state.n_retrains if state is not None
+                                   else 0))
+                prob = bs.attach()
+            resume_state = None
+            if state is not None:
+                ckpt.restore_into(state, prob, bs)
+                resume_state = state.ga_resume()
+            on_generation = saver = None
+            if store is not None:
+                final_prob, final_bs = prob, bs
+                # persistence overlaps the next generation's compute:
+                # capture copies only the new history suffix on this
+                # thread, the incremental encode + durable write happen on
+                # the saver's worker (FIFO-ordered, drained before run
+                # returns)
+                saver = ckpt.AsyncSaver(store, key, settings)
 
-            def on_generation(ga_state):
-                g = ga_state["next_gen"]
-                if g % max(1, checkpoint_every) == 0 or g == generations:
-                    saver.save(ga_state, final_prob, final_bs)
-        try:
-            res = run_search(prob, n_generations=generations, pop_size=pop,
-                             initial_pop_size=initial, seed=seed, log=log,
-                             batched=batched, on_generation=on_generation,
-                             resume_state=resume_state)
-        except BaseException:
+                def on_generation(ga_state):
+                    g = ga_state["next_gen"]
+                    if g % max(1, checkpoint_every) == 0 or g == generations:
+                        saver.save(ga_state, final_prob, final_bs)
+            try:
+                res = run_search(
+                    prob, n_generations=generations, pop_size=pop,
+                    initial_pop_size=initial, seed=seed, log=log,
+                    batched=batched, on_generation=on_generation,
+                    resume_state=resume_state)
+            except BaseException:
+                if saver is not None:
+                    saver.abort()   # already unwinding; don't mask it
+                raise
             if saver is not None:
-                saver.abort()   # already unwinding; don't mask this error
-            raise
-        if saver is not None:
-            saver.close()       # final write durable before run() returns
+                saver.close()   # final write durable before run() returns
         return SearchResult(self.target, prob, res, bs,
                             checkpoint_stats=(dict(saver.stats)
                                               if saver else None))

@@ -79,6 +79,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import faults as fault_policies
 from repro.distributed import pop_sharding
@@ -234,11 +235,13 @@ class PopulationEvaluator:
         # backend supports aliasing (not CPU).
         def _batch_err(params, banks, feats, labels, qp_stack):
             logits = forward_pop(params, feats, qp_stack, banks)
-            wrong = jnp.argmax(logits, -1) != labels[None]  # (P, B*, T)
-            if self._folded:
-                p, _, t = wrong.shape
-                return jnp.sum(wrong.reshape(p, n_sub, -1, t), axis=(2, 3))
-            return jnp.sum(wrong, axis=(1, 2))
+            with jax.named_scope("eval.count"):
+                wrong = jnp.argmax(logits, -1) != labels[None]  # (P,B*,T)
+                if self._folded:
+                    p, _, t = wrong.shape
+                    return jnp.sum(wrong.reshape(p, n_sub, -1, t),
+                                   axis=(2, 3))
+                return jnp.sum(wrong, axis=(1, 2))
 
         self._batch_err_fn = _batch_err
         self._pop_axis = pop_axis
@@ -332,26 +335,42 @@ class PopulationEvaluator:
             self.faults.on_dispatch(self)
         return self._batch_err(params, banks, feats, labels, stack)
 
+    def _subset_errors(self, params, banks, feats, labels, stack, p: int,
+                       frames: int) -> np.ndarray:
+        """One dispatch and its readback; returns the real lanes' error %
+        per subset. Three profiler spans split the host's part: the
+        enqueue (``evaluator.dispatch``, with the real and padded lane
+        counts), the wait for the device, and the readback with the
+        count-to-error% math. The copy to the host is queued with the
+        program, as ``jax.device_get`` queues it, so waiting apart from the
+        readback costs no second round trip to the device."""
+        with TraceAnnotation("evaluator.dispatch", lanes=p,
+                             bucket=int(stack.shape[0])):
+            out = self._dispatch(params, banks, feats, labels, stack)
+            if isinstance(out, jax.Array):
+                out.copy_to_host_async()
+        with TraceAnnotation("evaluator.wait"):
+            jax.block_until_ready(out)
+        with TraceAnnotation("evaluator.readback"):
+            wrong = np.asarray(pop_sharding.gather_counts(out))
+            return 100.0 * wrong[:p].astype(np.int64) / frames
+
     def _errors_once(self, allocs: Sequence[Alloc], params) -> np.ndarray:
         """One attempt at scoring a generation; returns the (P,) float
         max-over-subsets error array (real lanes only, padding sliced)."""
-        stack = self._stack(allocs)
+        with TraceAnnotation("evaluator.stack"):
+            stack = self._stack(allocs)
         banks = self._banks_for(params)
         p = len(allocs)
         if self._folded:
-            wrong = np.asarray(pop_sharding.gather_counts(self._dispatch(
-                params, banks, self._feats_all, self._labels_all,
-                stack)))                                             # (P, S)
-            errs = 100.0 * wrong[:p].astype(np.int64) / self._subset_frames
-            errs = np.max(errs, axis=1)
+            errs = np.max(self._subset_errors(
+                params, banks, self._feats_all, self._labels_all, stack, p,
+                self._subset_frames), axis=1)                # (P, S) -> (P,)
         else:
-            per_subset = []
-            for feats, labels in self.val_subsets:
-                wrong = np.asarray(pop_sharding.gather_counts(
-                    self._dispatch(params, banks, feats, labels, stack)))
-                per_subset.append(100.0 * wrong[:p].astype(np.int64)
-                                  / int(np.asarray(labels).size))
-            errs = np.max(np.stack(per_subset), axis=0)
+            errs = np.max(np.stack([
+                self._subset_errors(params, banks, feats, labels, stack, p,
+                                    int(np.asarray(labels).size))
+                for feats, labels in self.val_subsets]), axis=0)
         if self.faults is not None:
             errs = self.faults.on_result(self, errs)
         return errs
@@ -388,25 +407,27 @@ class PopulationEvaluator:
         ``DeviceLossError`` re-pads and re-dispatches the whole generation
         on the surviving mesh. Both paths preserve bit parity — a retry
         re-runs the identical program, and shard_map programs are exact
-        per shard."""
+        per shard. The whole call, retries included, is the profiler span
+        ``evaluator.errors``."""
         if not allocs:
             return []
-        attempt = 0
-        while True:
-            try:
-                return self._errors_once(allocs, params).tolist()
-            except fault_policies.DeviceLossError as loss:
-                self._survive_device_loss(loss.keep)
-            except fault_policies.TRANSIENT_DISPATCH_ERRORS as exc:
-                attempt += 1
-                if attempt > self.max_retries:
-                    raise
-                delay = self.retry_backoff_s * (2 ** (attempt - 1))
-                self.fault_log.append({
-                    "event": "retry", "attempt": attempt,
-                    "delay_s": delay,
-                    "error": f"{type(exc).__name__}: {exc}"})
-                time.sleep(delay)
+        with TraceAnnotation("evaluator.errors"):
+            attempt = 0
+            while True:
+                try:
+                    return self._errors_once(allocs, params).tolist()
+                except fault_policies.DeviceLossError as loss:
+                    self._survive_device_loss(loss.keep)
+                except fault_policies.TRANSIENT_DISPATCH_ERRORS as exc:
+                    attempt += 1
+                    if attempt > self.max_retries:
+                        raise
+                    delay = self.retry_backoff_s * (2 ** (attempt - 1))
+                    self.fault_log.append({
+                        "event": "retry", "attempt": attempt,
+                        "delay_s": delay,
+                        "error": f"{type(exc).__name__}: {exc}"})
+                    time.sleep(delay)
 
 
 class BatchedSRUEvaluator(PopulationEvaluator):

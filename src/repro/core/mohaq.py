@@ -20,6 +20,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.hardware import HardwareModel
 from repro.core.nsga2 import NSGA2, Individual
@@ -192,37 +193,42 @@ class MOHAQProblem:
         allocations are filled from the error memo, then the remaining
         allocations (deduplicated — distinct genomes can snap to one
         allocation) are scored in ONE ``batch_error_fn`` call (scalar
-        ``error_fn`` loop when no batched evaluator is wired)."""
-        results: List[Optional[Tuple[List[float], float]]] = \
-            [None] * len(genomes)
-        pending: List[Tuple[int, Alloc, tuple]] = []
-        fresh_keys: List[tuple] = []
-        fresh_allocs: List[Alloc] = []
-        for i, genome in enumerate(genomes):
-            alloc, violation = self._screen(genome)
-            if violation > 0.0:
-                results[i] = self._finish(alloc, float("inf"), violation)
-                continue
-            key = self._alloc_key(alloc)
-            if key in self.error_memo:
-                self.memo_hits += 1
-            elif key not in fresh_keys:
-                fresh_keys.append(key)
-                fresh_allocs.append(alloc)
-            else:                      # duplicate within this batch
-                self.memo_hits += 1
-            pending.append((i, alloc, key))
-        if fresh_allocs:
-            if self.batch_error_fn is not None:
-                errs = list(self.batch_error_fn(fresh_allocs))
-            else:
-                errs = [self.error_fn(a) for a in fresh_allocs]
-            for key, err in zip(fresh_keys, errs):
-                self.error_memo[key] = float(err)
-                self.n_error_evals += 1
-        for i, alloc, key in pending:
-            results[i] = self._finish(alloc, self.error_memo[key], 0.0)
-        return results
+        ``error_fn`` loop when no batched evaluator is wired). Profiler
+        spans: ``mohaq.evaluate`` (the whole call) and, inside it,
+        ``mohaq.objectives`` (the hardware objectives of the screened-in
+        genomes)."""
+        with TraceAnnotation("mohaq.evaluate"):
+            results: List[Optional[Tuple[List[float], float]]] = \
+                [None] * len(genomes)
+            pending: List[Tuple[int, Alloc, tuple]] = []
+            fresh_keys: List[tuple] = []
+            fresh_allocs: List[Alloc] = []
+            for i, genome in enumerate(genomes):
+                alloc, violation = self._screen(genome)
+                if violation > 0.0:
+                    results[i] = self._finish(alloc, float("inf"), violation)
+                    continue
+                key = self._alloc_key(alloc)
+                if key in self.error_memo:
+                    self.memo_hits += 1
+                elif key not in fresh_keys:
+                    fresh_keys.append(key)
+                    fresh_allocs.append(alloc)
+                else:                      # duplicate within this batch
+                    self.memo_hits += 1
+                pending.append((i, alloc, key))
+            if fresh_allocs:
+                if self.batch_error_fn is not None:
+                    errs = list(self.batch_error_fn(fresh_allocs))
+                else:
+                    errs = [self.error_fn(a) for a in fresh_allocs]
+                for key, err in zip(fresh_keys, errs):
+                    self.error_memo[key] = float(err)
+                    self.n_error_evals += 1
+            with TraceAnnotation("mohaq.objectives"):
+                for i, alloc, key in pending:
+                    results[i] = self._finish(alloc, self.error_memo[key], 0.0)
+            return results
 
     def _pack(self, err: float, hw: Dict[str, float]) -> List[float]:
         objs = []

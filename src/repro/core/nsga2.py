@@ -1,4 +1,6 @@
-"""NSGA-II (Deb et al. 2002) on integer genomes — pure numpy.
+"""NSGA-II (Deb et al. 2002) on integer genomes — pure numpy, with the
+profiler's host spans (``ga.initial``, ``ga.generation`` and, inside a
+generation, ``ga.rank``, ``ga.offspring``, ``ga.survive``) from JAX.
 
 pymoo is unavailable offline; this implements the same algorithm the paper
 uses via pymoo: fast non-dominated sort, crowding distance, binary-tournament
@@ -13,6 +15,7 @@ from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -268,6 +271,37 @@ class NSGA2:
                 out.append(c)
         return out[:self.pop_size]
 
+    def _generation(self, gen: int, key, pop: List[Individual],
+                    cache: dict) -> List[Individual]:
+        """One generation: rank and crowd the population, breed and score
+        ``pop_size`` children, and keep the best ``pop_size`` of both
+        (elitist survival). ``key`` seeds the variation stream."""
+        with TraceAnnotation("ga.rank"):
+            for front in fast_non_dominated_sort(pop):
+                assign_crowding(front)
+        with TraceAnnotation("ga.offspring"):
+            genomes = self._offspring(np.random.default_rng(key), pop)
+        children = self._eval_many(genomes, cache)
+        with TraceAnnotation("ga.survive"):
+            survivors: List[Individual] = []
+            for front in fast_non_dominated_sort(pop + children):
+                assign_crowding(front)
+                if len(survivors) + len(front) <= self.pop_size:
+                    survivors.extend(front)
+                else:
+                    front.sort(key=lambda s: -s.crowding)
+                    survivors.extend(front[:self.pop_size - len(survivors)])
+                    break
+        return survivors
+
+    def _log_generation(self, gen: int, pop: List[Individual]) -> None:
+        best = min(p.objectives[0] for p in pop if p.violation == 0) \
+            if any(p.violation == 0 for p in pop) else float("nan")
+        self.log(f"gen {gen + 1}/{self.n_generations} "
+                 f"evals={len(self.history)} "
+                 f"cache_hits={self.n_cache_hits} "
+                 f"best_obj0={best:.3f}")
+
     def run(self, *, resume: Optional[dict] = None,
             on_generation: Optional[Callable[[dict], None]] = None
             ) -> List[Individual]:
@@ -314,36 +348,18 @@ class NSGA2:
                    for i in resume["population"]]
         else:
             start_gen = 0
-            rng = np.random.default_rng(keys[0])
-            pop = self._eval_many(
-                [rng.integers(self.var_lo, self.var_hi + 1, self.n_var)
-                 for _ in range(self.initial_pop_size)], cache)
-            notify(0, pop)
+            with TraceAnnotation("ga.initial"):
+                rng = np.random.default_rng(keys[0])
+                pop = self._eval_many(
+                    [rng.integers(self.var_lo, self.var_hi + 1, self.n_var)
+                     for _ in range(self.initial_pop_size)], cache)
+                notify(0, pop)
         for gen in range(start_gen, self.n_generations):
-            for front in fast_non_dominated_sort(pop):
-                assign_crowding(front)
-            children = self._eval_many(
-                self._offspring(np.random.default_rng(keys[1 + gen]), pop),
-                cache)
-            merged = pop + children
-            survivors: List[Individual] = []
-            for front in fast_non_dominated_sort(merged):
-                assign_crowding(front)
-                if len(survivors) + len(front) <= self.pop_size:
-                    survivors.extend(front)
-                else:
-                    front.sort(key=lambda s: -s.crowding)
-                    survivors.extend(front[:self.pop_size - len(survivors)])
-                    break
-            pop = survivors
-            notify(gen + 1, pop)
-            if self.log:
-                best = min(p.objectives[0] for p in pop if p.violation == 0) \
-                    if any(p.violation == 0 for p in pop) else float("nan")
-                self.log(f"gen {gen + 1}/{self.n_generations} "
-                         f"evals={len(self.history)} "
-                         f"cache_hits={self.n_cache_hits} "
-                         f"best_obj0={best:.3f}")
+            with TraceAnnotation("ga.generation", gen=gen):
+                pop = self._generation(gen, keys[1 + gen], pop, cache)
+                notify(gen + 1, pop)
+                if self.log:
+                    self._log_generation(gen, pop)
         feasible = [p for p in pop if p.violation == 0.0]
         fronts = fast_non_dominated_sort(feasible or pop)
         return _dedup(fronts[0])

@@ -97,19 +97,25 @@ def forward(params, cfg: ArchConfig, tokens, get_w, q_act):
     replacement dict for the layer's quantizable leaves; ``q_act(name, x)``
     -> the (possibly fake-quantized) block-input activation. The group loop
     is unrolled in Python (G is tiny for search configs) so per-layer grids
-    need no scan threading. Returns f32 logits (B, T, V)."""
+    need no scan threading. Returns f32 logits (B, T, V). Named scopes
+    (``xlstm.mlstm``, ``xlstm.slstm_scan``, ``xlstm.head``; inside them
+    ``xlstm.weight_gather`` in ``forward_population``'s banked lane) carry
+    into the compiled program's op metadata."""
     x = tfm.embed_tokens(params, cfg, tokens)
     for g in range(cfg.n_layers // 2):
         bp = jax.tree.map(lambda a, _g=g: a[_g], params["pairs"])
         m, s = f"m{g}", f"s{g}"
         xin = q_act(m, cm.rms_norm(x, bp["norm_m"], cfg.norm_eps))
-        x = x + xlstm.mlstm_fwd({**bp["mlstm"], **get_w(m)}, cfg, xin)
+        with jax.named_scope("xlstm.mlstm"):
+            x = x + xlstm.mlstm_fwd({**bp["mlstm"], **get_w(m)}, cfg, xin)
         xin = q_act(s, cm.rms_norm(x, bp["norm_s"], cfg.norm_eps))
-        x = x + xlstm.slstm_fwd({**bp["slstm"], **get_w(s)}, cfg, xin)
-    x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    xq = q_act("head", x)
-    return jnp.dot(xq, get_w("head")["lm_head"],
-                   preferred_element_type=jnp.float32)
+        with jax.named_scope("xlstm.slstm_scan"):
+            x = x + xlstm.slstm_fwd({**bp["slstm"], **get_w(s)}, cfg, xin)
+    with jax.named_scope("xlstm.head"):
+        x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        xq = q_act("head", x)
+        return jnp.dot(xq, get_w("head")["lm_head"],
+                       preferred_element_type=jnp.float32)
 
 
 def forward_plain(params, cfg: ArchConfig, tokens):
@@ -145,8 +151,9 @@ def forward_population(params, cfg: ArchConfig, tokens, qp_stack,
         else:
             def get_w(name):
                 idx = Q.menu_index_from_hi(row[li[name], 2])
-                return {k: jnp.take(b, idx, axis=0)
-                        for k, b in banks[name].items()}
+                with jax.named_scope("xlstm.weight_gather"):
+                    return {k: jnp.take(b, idx, axis=0)
+                            for k, b in banks[name].items()}
 
         return forward(params, cfg, tokens, get_w, q_act)
 

@@ -456,7 +456,9 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
     scanned ``reverse=True`` (no stack/flip copies, dead reset-gate output
     elided, larger exact unroll); ``use_kernel=True`` streams through the
     population-axis Pallas kernel (one call per direction,
-    grid (P, B/bb, n/bn))."""
+    grid (P, B/bb, n/bn)). Named scopes (``sru.bank_gather``,
+    ``sru.u0_bank``, ``sru.projection`` for every MxV, ``sru.scan``) carry
+    into the compiled program's op metadata."""
     names = list(cfg.layer_names())
     li = {n: i for i, n in enumerate(names)}
     P = qp_stack.shape[0]
@@ -495,13 +497,16 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
     def lane_w(name, sub=None):
         """(P, m, h) per-lane quantized weight: bank gather or requant."""
         if banks is not None:
-            return jnp.take(bank_of(name, sub), w_idx[:, li[name]], axis=0)
+            with jax.named_scope("sru.bank_gather"):
+                return jnp.take(bank_of(name, sub), w_idx[:, li[name]],
+                                axis=0)
         w = params[name]["W"] if sub is None else params[name][sub]["W"]
         return q_w(name, w)
 
     def mxv(xq, wq):                          # (P,B,T,m) @ (P,m,h)
-        out = jnp.matmul(xq.reshape(P, -1, xq.shape[-1]), wq)
-        return out.reshape(xq.shape[:3] + (wq.shape[-1],))
+        with jax.named_scope("sru.projection"):
+            out = jnp.matmul(xq.reshape(P, -1, xq.shape[-1]), wq)
+            return out.reshape(xq.shape[:3] + (wq.shape[-1],))
 
     def mxv_layer(xq, name, sub=None):
         """Per-lane quantized MxV. With banks + kernel the gather happens
@@ -511,7 +516,9 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
         if banks is not None and use_kernel:
             from repro.kernels import ops as kops
             x2 = xq.reshape(P, -1, xq.shape[-1])
-            u = kops.bank_step(x2, raw_bank(name, sub), w_idx[:, li[name]])
+            with jax.named_scope("sru.projection"):
+                u = kops.bank_step(x2, raw_bank(name, sub),
+                                   w_idx[:, li[name]])
             return u.reshape(xq.shape[:3] + (u.shape[-1],))
         return mxv(xq, lane_w(name, sub))
 
@@ -562,9 +569,9 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
                     # the u-bank the broadcast input (the usual anchor) is
                     # dead code, so GSPMD must pick the partitioning up
                     # from the gathered stream
-                    u = dist_shard(
-                        jnp.take(banks[name][key]["U"], combo, axis=0),
-                        "pop")
+                    with jax.named_scope("sru.u0_bank"):
+                        u = jnp.take(banks[name][key]["U"], combo, axis=0)
+                    u = dist_shard(u, "pop")
                 else:
                     u = mxv_layer(xq, name, key)             # (P,B,T,3n)
                 uw, uf, ur = u[..., :n], u[..., n:2 * n], u[..., 2 * n:]
@@ -579,10 +586,11 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
                                    else (r * c_new,))
 
                 tr = lambda a: a.transpose(2, 0, 1, 3)       # (T,P,B,n)
-                _, out = jax.lax.scan(
-                    step, jnp.zeros((P, x.shape[1], n), jnp.float32),
-                    (tr(uw), tr(uf), tr(ur)),
-                    unroll=_BANK_SCAN_UNROLL, reverse=(key == "bwd"))
+                with jax.named_scope("sru.scan"):
+                    _, out = jax.lax.scan(
+                        step, jnp.zeros((P, x.shape[1], n), jnp.float32),
+                        (tr(uw), tr(uf), tr(ur)),
+                        unroll=_BANK_SCAN_UNROLL, reverse=(key == "bwd"))
                 h = out[0].transpose(1, 2, 0, 3)             # (P,B,T,n)
                 if highway:                                  # aligned: no flip
                     h = h + (1.0 - out[1].transpose(1, 2, 0, 3)) * xq
@@ -597,7 +605,8 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
         for key in ("fwd", "bwd"):
             dp = lp[key]
             if use_u0:
-                u = jnp.take(banks[name][key]["U"], combo, axis=0)
+                with jax.named_scope("sru.u0_bank"):
+                    u = jnp.take(banks[name][key]["U"], combo, axis=0)
             else:
                 u = mxv_layer(xq, name, key)                 # (P,B,T,3n)
             uw, uf, ur = u[..., :n], u[..., n:2 * n], u[..., 2 * n:]
@@ -614,7 +623,9 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
             from repro.kernels import ops as kops
             hs = []
             for (uw, uf, ur), (v, b) in zip(streams, vecs):
-                h, r = kops.sru_scan_pop(uw, uf, ur, v[0], v[1], b[0], b[1])
+                with jax.named_scope("sru.scan"):
+                    h, r = kops.sru_scan_pop(uw, uf, ur, v[0], v[1], b[0],
+                                             b[1])
                 if x.shape[-1] == n:                         # highway skip
                     hs_in = xq if len(hs) == 0 else xq[:, :, ::-1]
                     h = h + (1.0 - r) * hs_in
@@ -636,11 +647,13 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
                 return c_new, (r * c_new, r)
 
             c0 = jnp.zeros((2, P, x.shape[1], n), jnp.float32)
-            _, (h, r) = jax.lax.scan(
-                step, c0,
-                (UW.transpose(3, 0, 1, 2, 4), UF.transpose(3, 0, 1, 2, 4),
-                 UR.transpose(3, 0, 1, 2, 4)),
-                unroll=_POP_SCAN_UNROLL)
+            with jax.named_scope("sru.scan"):
+                _, (h, r) = jax.lax.scan(
+                    step, c0,
+                    (UW.transpose(3, 0, 1, 2, 4),
+                     UF.transpose(3, 0, 1, 2, 4),
+                     UR.transpose(3, 0, 1, 2, 4)),
+                    unroll=_POP_SCAN_UNROLL)
             h = h.transpose(1, 2, 3, 0, 4)                   # (2,P,B,T,n)
             r = r.transpose(1, 2, 3, 0, 4)
             if x.shape[-1] == n:                             # highway skip

@@ -178,6 +178,53 @@ def test_r3_sees_jit_call_form_and_partial_decorator():
     assert _rules(out) == ["R3", "R3"]
 
 
+_SPAN_AROUND_CALL = """
+    import jax
+    from jax.profiler import TraceAnnotation
+    @jax.jit
+    def step(x):
+        with jax.named_scope("step.body"):
+            return x * 2
+    def run(x):
+        with TraceAnnotation("step", lanes=4):
+            return step(x)
+"""
+
+
+def test_r3_allows_profiler_span_around_jitted_call():
+    """The program's idiom: the host span around the call, a named scope
+    inside the traced body."""
+    assert _analyze(_SPAN_AROUND_CALL, path=PLAIN_PATH) == []
+
+
+def test_r3_flags_profiler_span_moved_into_jitted_body():
+    """The same module with the span moved inside ``step``: it would time
+    the tracing and record nothing when the program runs."""
+    broken = _SPAN_AROUND_CALL.replace(
+        'with jax.named_scope("step.body"):',
+        'with jax.profiler.TraceAnnotation("step.body"):')
+    out = _analyze(broken, path=PLAIN_PATH)
+    assert _rules(out) == ["R3"]
+    assert "jax.profiler.TraceAnnotation" in out[0].message
+    assert "`step`" in out[0].message and out[0].line == 6
+
+
+def test_r3_flags_step_span_in_shard_map_body():
+    out = _analyze("""
+        import jax
+        from jax.profiler import StepTraceAnnotation as Step
+        def body(x):
+            with Step("shard", step_num=0):
+                return x + 1
+        f = jax.shard_map(body, mesh=None, in_specs=None, out_specs=None)
+        def host(x):
+            with Step("host", step_num=0):
+                return f(x)
+    """, path=PLAIN_PATH)
+    assert _rules(out) == ["R3"]
+    assert "StepTraceAnnotation" in out[0].message and out[0].line == 5
+
+
 # --------------------------------------------------------------- R4
 
 def test_r4_flags_mutable_default_and_float_static():

@@ -12,7 +12,9 @@ R2  Deprecated entrypoints — no calls to the ``sru_experiment`` shims
     its tests; new code goes through ``repro.core.api``.
 R3  Host side effects inside jit — ``print``, ``.item()``,
     ``np.asarray``/``np.array``, ``jax.debug.*`` inside a jit/shard_map-
-    compiled function break tracing or silently sync the device. An
+    compiled function break tracing or silently sync the device; a
+    ``jax.profiler.TraceAnnotation``/``StepTraceAnnotation`` there spans
+    the trace, not the run (``jax.named_scope`` names device ops). An
     ``# analyze: allow=R3 <reason>`` comment on the line suppresses.
 R4  Retrace hazards — mutable default args on jitted functions, and
     ``static_argnames`` naming float-valued/mutable-default (or
@@ -50,6 +52,11 @@ _DEPRECATED_ENTRYPOINTS = {
     "experiment3_bitfusion",
 }
 _SHIM_MODULE = "repro.core.sru_experiment"
+
+# profiler host spans: entered on the host, so inside a traced body they
+# time the tracing, not the device work
+_HOST_SPANS = ("jax.profiler.TraceAnnotation",
+               "jax.profiler.StepTraceAnnotation")
 
 _PARITY_FROZEN = (
     "repro/models/sru.py", "repro/core/quantization.py",
@@ -171,6 +178,12 @@ class HostSideEffectRule(Rule):
                         ctx, node, f"jax.debug.{func.attr}() {where} "
                         "without an allowlist comment "
                         "(# analyze: allow=R3 <reason>)")
+                elif ctx.resolve_call_target(func) in _HOST_SPANS:
+                    yield self.finding(
+                        ctx, node, f"{ctx.resolve_call_target(func)} "
+                        f"{where} opens its span at trace time only and "
+                        "records nothing when the program runs; put it "
+                        "around the call, or use jax.named_scope inside")
 
 
 def _is_mutable_literal(node: ast.AST) -> bool:
