@@ -156,6 +156,12 @@ class PopulationEvaluator:
     program) or ``"gspmd"`` (jit with PartitionSpecs). Banks replicate per
     shard (like params) and the row gather runs inside each shard's
     program, so single-device behaviour and error counts are unchanged.
+
+    ``dispatch_stats`` (optional): ``(lanes, banks) -> {stat: int}``, more
+    stats for each dispatch's ``evaluator.dispatch`` profiler span, given
+    the lane count ``forward_pop`` traces (per shard under ``shard_map``)
+    and the banks it is passed (the xLSTM target records which sLSTM
+    recurrence its forward takes).
     """
 
     def __init__(self, layer_names, val_subsets,
@@ -169,7 +175,8 @@ class PopulationEvaluator:
                  extend_banks: Optional[Callable] = None,
                  menu_bits=None,
                  bank_format: str = "f32",
-                 make_packed_banks: Optional[Callable] = None):
+                 make_packed_banks: Optional[Callable] = None,
+                 dispatch_stats: Optional[Callable] = None):
         from repro.core import quantization as Q
 
         self.layer_names = list(layer_names)
@@ -210,6 +217,7 @@ class PopulationEvaluator:
         self._make_banks = make_banks
         self._make_packed_banks = make_packed_banks
         self._extend_banks = extend_banks
+        self._dispatch_stats = dispatch_stats
         # banks keyed by parameter-set identity; the params ref is kept so
         # a collected object's id can never alias a live cache entry
         self._banks: Dict[int, tuple] = {}
@@ -344,8 +352,14 @@ class PopulationEvaluator:
         count-to-error% math. The copy to the host is queued with the
         program, as ``jax.device_get`` queues it, so waiting apart from the
         readback costs no second round trip to the device."""
-        with TraceAnnotation("evaluator.dispatch", lanes=p,
-                             bucket=int(stack.shape[0])):
+        bucket = int(stack.shape[0])
+        stats = {}
+        if self._dispatch_stats is not None:
+            traced = (bucket // self._n_shards
+                      if self._partition == "shard_map" else bucket)
+            stats = self._dispatch_stats(traced, banks)
+        with TraceAnnotation("evaluator.dispatch", lanes=p, bucket=bucket,
+                             **stats):
             out = self._dispatch(params, banks, feats, labels, stack)
             if isinstance(out, jax.Array):
                 out.copy_to_host_async()

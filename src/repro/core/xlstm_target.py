@@ -28,6 +28,10 @@ Per-layer quantized-weight banks: every quantizable leaf gets a
 forward gathers each lane's row by menu index (recovered from the qp grid
 tops via ``menu_index_from_hi``) instead of requantizing per lane — the
 same gather-don't-requantize contract the SRU banks established (PR 4).
+The sLSTM recurrent kernel ``r`` is the exception once a dispatch traces
+more lanes than the bank has rows (P > 4): it is not gathered, and each
+scan step contracts all lanes' states against the bank's rows and keeps
+each lane's own (``slstm_menu_engaged``, ``_menu_product``).
 
 Error metric: next-token top-1 error % on a bigram-structured synthetic LM
 task, MAX over 4 validation subsets (the paper's §4.2 ranking trick),
@@ -92,15 +96,18 @@ def _layer_leaves(params, cfg: ArchConfig, name: str) -> Dict[str, jnp.ndarray]:
     return {k: sub[k] for k in QUANT_LEAVES[name[0]]}
 
 
-def forward(params, cfg: ArchConfig, tokens, get_w, q_act):
+def forward(params, cfg: ArchConfig, tokens, get_w, q_act, get_rec=None):
     """The block-pair forward with quantization hooks. ``get_w(name)`` ->
     replacement dict for the layer's quantizable leaves; ``q_act(name, x)``
-    -> the (possibly fake-quantized) block-input activation. The group loop
-    is unrolled in Python (G is tiny for search configs) so per-layer grids
-    need no scan threading. Returns f32 logits (B, T, V). Named scopes
-    (``xlstm.mlstm``, ``xlstm.slstm_scan``, ``xlstm.head``; inside them
-    ``xlstm.weight_gather`` in ``forward_population``'s banked lane) carry
-    into the compiled program's op metadata."""
+    -> the (possibly fake-quantized) block-input activation; ``get_rec``
+    (optional), name -> the sLSTM layer's recurrent product ``h -> h @ r``
+    in place of its ``r`` leaf. The group loop is unrolled in Python (G is
+    tiny for search configs) so per-layer grids need no scan threading.
+    Returns f32 logits (B, T, V). Named scopes (``xlstm.mlstm``,
+    ``xlstm.slstm_scan``, ``xlstm.head``; inside them
+    ``xlstm.weight_gather`` and ``xlstm.slstm_menu`` in
+    ``forward_population``'s banked lane) carry into the compiled
+    program's op metadata."""
     x = tfm.embed_tokens(params, cfg, tokens)
     for g in range(cfg.n_layers // 2):
         bp = jax.tree.map(lambda a, _g=g: a[_g], params["pairs"])
@@ -110,7 +117,8 @@ def forward(params, cfg: ArchConfig, tokens, get_w, q_act):
             x = x + xlstm.mlstm_fwd({**bp["mlstm"], **get_w(m)}, cfg, xin)
         xin = q_act(s, cm.rms_norm(x, bp["norm_s"], cfg.norm_eps))
         with jax.named_scope("xlstm.slstm_scan"):
-            x = x + xlstm.slstm_fwd({**bp["slstm"], **get_w(s)}, cfg, xin)
+            x = x + xlstm.slstm_fwd({**bp["slstm"], **get_w(s)}, cfg, xin,
+                                    rec_fn=get_rec(s) if get_rec else None)
     with jax.named_scope("xlstm.head"):
         x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         xq = q_act("head", x)
@@ -125,21 +133,57 @@ def forward_plain(params, cfg: ArchConfig, tokens):
                    lambda name, x: x)
 
 
+def slstm_menu_engaged(banks, lanes: int) -> bool:
+    """Whether ``forward_population`` contracts the sLSTM recurrence
+    against the menu rows of its bank: banks are given and the traced lane
+    count is above the bank's row count K. At ``lanes <= K`` gathering each
+    lane's ``r`` reads no more rows and skips the K-wide product."""
+    rows = [b["r"].shape[0] for b in (banks or {}).values() if "r" in b]
+    return bool(rows) and lanes > rows[0]
+
+
+def _menu_product(bank, idx):
+    """The sLSTM recurrent product of one lane whose ``r`` is row ``idx``
+    of ``bank`` (K, H, dh, 4*dh), without gathering that row: one dot
+    contracts the lane's state against all K rows (under ``jax.vmap`` the
+    bank stays unbatched, so that is one dot for every lane's rows at
+    once), then an exact select keeps row ``idx``. The product the lane
+    keeps is its ``h`` times its ``r``, the same operands as the gathered
+    einsum; the select, unlike a one-hot sum, leaves a lane finite when
+    another row overflows."""
+    def rec(h):
+        with jax.named_scope("xlstm.slstm_menu"):
+            # the bank as the dot's first operand: the CPU then sums each
+            # product in the per-lane einsum's order (bit for bit at the
+            # tests' widths)
+            every = jnp.einsum("khde,bhd->kbhe", bank, h)
+            return jax.lax.select_n(idx, *every)
+    return rec
+
+
 def forward_population(params, cfg: ArchConfig, tokens, qp_stack,
                        banks=None):
     """Score P quantization candidates in one call: vmap of the hooked
     forward over the (P, L, 6) qp grid stack (params/tokens broadcast).
     With ``banks`` each lane's quantized leaves are *gathered* by menu
     index — rows are built by the identical jitted ``fake_quant_triple``
-    expression, so the gather lane matches the requant lane exactly."""
+    expression, so the gather lane matches the requant lane exactly —
+    except the sLSTM recurrent kernel ``r`` once P exceeds the bank's K
+    menu rows (``slstm_menu_engaged``): each step then contracts every
+    lane's state against the K rows and selects the lane's own
+    (``_menu_product``), so the scan carries the (K, H, dh, 4*dh) bank
+    instead of P gathered copies. P is the lane count this function
+    traces, per shard under a ``shard_map`` pop mesh."""
     names = quant_layer_names(cfg)
     li = {n: i for i, n in enumerate(names)}
+    menu_rec = slstm_menu_engaged(banks, qp_stack.shape[0])
 
     def one(row):                                   # (L, 6) per lane
         def q_act(name, x):
             r = row[li[name]]
             return Q.fake_quant_triple(x, r[3], r[4], r[5])
 
+        get_rec = None
         if banks is None:
             def get_w(name):
                 # pure grid values (use_ste=False) — matches the bank rows
@@ -149,13 +193,21 @@ def forward_population(params, cfg: ArchConfig, tokens, qp_stack,
                                                use_ste=False)
                         for k, w in leaves.items()}
         else:
+            def idx_of(name):
+                return Q.menu_index_from_hi(row[li[name], 2])
+
             def get_w(name):
-                idx = Q.menu_index_from_hi(row[li[name], 2])
+                idx = idx_of(name)
                 with jax.named_scope("xlstm.weight_gather"):
                     return {k: jnp.take(b, idx, axis=0)
-                            for k, b in banks[name].items()}
+                            for k, b in banks[name].items()
+                            if not (menu_rec and k == "r")}
 
-        return forward(params, cfg, tokens, get_w, q_act)
+            if menu_rec:
+                def get_rec(name):
+                    return _menu_product(banks[name]["r"], idx_of(name))
+
+        return forward(params, cfg, tokens, get_w, q_act, get_rec)
 
     return jax.vmap(one)(qp_stack)
 
@@ -339,11 +391,15 @@ class XLSTMTarget:
                 return forward_population(params, cfg, feats, qp_stack,
                                           banks=banks)
 
+            def dispatch_stats(lanes, banks):
+                return {"slstm_menu": int(slstm_menu_engaged(banks, lanes))}
+
             self._evaluators[key] = batched_eval.PopulationEvaluator(
                 self.layer_names, self.val_subsets, self.qp_for,
                 forward_pop, mesh=mesh, partition=partition,
                 make_banks=self.make_banks, use_banks=use_banks,
-                qp_tables=self.qp_menu_tables(), menu_bits=self.menu)
+                qp_tables=self.qp_menu_tables(), menu_bits=self.menu,
+                dispatch_stats=dispatch_stats)
         return self._evaluators[key]
 
     def val_error_batch(self, allocs, params=None, *, mesh=None,

@@ -181,10 +181,15 @@ SLSTM_AXES = {"wx": ("embed", "ssm_inner"), "r": ("heads", None, None),
               "bias": ("ssm_inner",), "wo": ("ssm_inner", "embed")}
 
 
-def _slstm_cell(p, cfg, pre, state):
-    """pre: (B,H,dh,4) gate pre-activations (x-part); state dict."""
+def _slstm_cell(p, cfg, pre, state, rec_fn=None):
+    """pre: (B,H,dh,4) gate pre-activations (x-part); state dict.
+    ``rec_fn(h)`` -> (B,H,4*dh) replaces the recurrent product with
+    ``p["r"]`` (the search's banked population forward passes one)."""
     c, n, h, m = state["c"], state["n"], state["h"], state["m"]
-    rec = jnp.einsum("bhd,hde->bhe", h, p["r"])               # (B,H,4*dh)
+    if rec_fn is None:
+        rec = jnp.einsum("bhd,hde->bhe", h, p["r"])           # (B,H,4*dh)
+    else:
+        rec = rec_fn(h)
     B, H = h.shape[0], h.shape[1]
     dh = h.shape[2]
     rec = rec.reshape(B, H, 4, dh).transpose(0, 1, 3, 2)
@@ -210,7 +215,8 @@ def slstm_init_state(cfg, B):
             "m": jnp.zeros((B, H, dh), jnp.float32)}
 
 
-def slstm_fwd(p, cfg, x, return_state: bool = False):
+def slstm_fwd(p, cfg, x, return_state: bool = False, rec_fn=None):
+    """x: (B,T,D) -> (B,T,D); ``rec_fn`` as in ``_slstm_cell``."""
     B, T, D = x.shape
     H = cfg.n_heads
     di = cfg.ssm_d_inner
@@ -219,7 +225,7 @@ def slstm_fwd(p, cfg, x, return_state: bool = False):
            + p["bias"]).reshape(B, T, H, dh, 4)
 
     def step(state, pre_t):
-        new = _slstm_cell(p, cfg, pre_t, state)
+        new = _slstm_cell(p, cfg, pre_t, state, rec_fn)
         return new, new["h"]
 
     state0 = slstm_init_state(cfg, B)

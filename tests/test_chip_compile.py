@@ -1,6 +1,8 @@
 """The main path's Pallas kernels, compiled for a TPU v5e at the paper's
 widths (``PAPER_CFG``: Bi-SRU, input 23, hidden 550 per direction, proj 256,
-4 SRU layers, 1904 outputs) without a chip attached.
+4 SRU layers, 1904 outputs) without a chip attached, and the xLSTM banked
+population forward at the registry xlstm-350m's widths, whose compiled
+sLSTM loop is checked for what it carries.
 
 Interpret mode accepts block shapes and stores the TPU's compiler refuses;
 these compiles run Mosaic itself on a described ``v5e:2x2`` topology, so a
@@ -11,14 +13,19 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs import get_config
+from repro.core import xlstm_target as XT
 from repro.core.sru_experiment import PAPER_CFG
 from repro.kernels import ops
+from repro.models import registry
 
 P = 16                  # population lanes (one search generation)
 B, T = 32, 48           # sequences x frames per lane
@@ -102,3 +109,31 @@ def test_quant_matmul(spec, bits):
     _compiled_kernel(ops.quant_matmul.lower(
         spec((M, k)), spec((k * bits // 8, n), jnp.int8), spec((n,)),
         bits=bits, interpret=False).compile())
+
+
+def test_slstm_menu_recurrence(spec):
+    """The xLSTM population forward with banks at the registry xlstm-350m's
+    widths (d_model 1024, 4 heads of 512) and P = 16 lanes, cut to one
+    mLSTM/sLSTM pair, a 256-token vocabulary and 8 tokens: the sLSTM scan
+    carries the bank's K = 4 rows of ``r``, (4, 4, 512, 2048), and no
+    lane's own copy, (16, 4, 512, 2048)."""
+    cfg = dataclasses.replace(get_config("xlstm-350m"), n_layers=2,
+                              vocab_size=256)
+    names = XT.quant_layer_names(cfg)
+    params = jax.eval_shape(registry.get_model(cfg).init,
+                            jax.random.PRNGKey(0))
+    leaves = jax.eval_shape(lambda p: {n: XT._layer_leaves(p, cfg, n)
+                                       for n in names}, params)
+    on_chip = lambda t, lead=(): jax.tree.map(
+        lambda a: spec(lead + a.shape, a.dtype), t)
+    text = jax.jit(
+        lambda p, b, t, s: XT.forward_population(p, cfg, t, s, banks=b)
+    ).lower(on_chip(params), on_chip(leaves, (4,)),
+            spec((4, 8), jnp.int32), spec((16, len(names), 6))
+            ).compile().as_text()
+    carried = [{tuple(int(d) for d in m.split(","))
+                for m in re.findall(r"\[([\d,]+)\]", line.split(" while(")[0])}
+               for line in text.splitlines() if " while(" in line]
+    assert carried
+    assert not any((16, 4, 512, 2048) in c for c in carried)
+    assert any((4, 4, 512, 2048) in c for c in carried)

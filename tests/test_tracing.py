@@ -5,9 +5,10 @@ host plane of the trace is read back with ``ProfileData``.
 Checked: every span of the search path appears, nested as the program
 states; one ``ga.generation`` per generation with its ``gen``; the real
 lanes of the window's dispatches add up to the allocations scored, and each
-dispatch's padded size is the compile bucket; the unfolded per-subset path
-splits each subset's dispatch the same way; and tracing changes no result
-(the Pareto front equals an untraced run's of the same seed).
+dispatch's padded size is the compile bucket; the xLSTM's dispatches say
+which sLSTM recurrence they took (``slstm_menu``); the unfolded per-subset
+path splits each subset's dispatch the same way; and tracing changes no
+result (the Pareto front equals an untraced run's of the same seed).
 """
 import glob
 import os
@@ -17,12 +18,14 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from repro.core import quantization as Q
 from repro.core import sru_experiment as X
 from repro.core import xlstm_target as XT
 from repro.core.api import SearchSession
 from repro.core.batched_eval import BatchedSRUEvaluator, bucket_size
 
 GENERATIONS = 3
+MENU_ROWS = len(Q.SUPPORTED_BITS)      # K, the rows of each weight bank
 
 # span -> the program spans it may sit directly inside
 PARENTS = {
@@ -119,6 +122,37 @@ def test_dispatch_bucket_is_the_compile_bucket(searched):
     stats = [s[3] for s in spans if s[0] == "evaluator.dispatch"]
     assert stats and all(st["bucket"] == bucket_size(st["lanes"])
                          for st in stats)
+
+
+def test_dispatch_records_the_slstm_recurrence_taken(searched, request):
+    """Each xLSTM dispatch says whether its sLSTM recurrence contracted
+    against the bank's K menu rows: ``slstm_menu`` is 1 exactly for buckets
+    above K. The SRU's dispatches carry no such stat."""
+    _, spans, _, _ = searched
+    stats = [s[3] for s in spans if s[0] == "evaluator.dispatch"]
+    if request.node.callspec.params["searched"] == "xlstm":
+        assert all(st["slstm_menu"] == int(st["bucket"] > MENU_ROWS)
+                   for st in stats)
+        assert any(st["slstm_menu"] for st in stats)
+    else:
+        assert stats and all("slstm_menu" not in st for st in stats)
+
+
+def test_slstm_menu_stat_follows_bucket_and_banks(xlstm_target, tmp_path):
+    """Buckets on both sides of K, and the requant lane (no banks, so no
+    menu contraction at any bucket)."""
+    target = xlstm_target
+    allocs = [{n: (b, b) for n in target.layer_names}
+              for b in (2, 4, 8, 16, 2)]
+    banked = target.batched_evaluator()
+    requant = target.batched_evaluator(use_banks=False)
+    with jax.profiler.trace(str(tmp_path)):
+        banked.errors(allocs[:3], target.params)
+        banked.errors(allocs, target.params)
+        requant.errors(allocs, target.params)
+    got = [(s[3]["bucket"], s[3]["slstm_menu"])
+           for s in host_spans(str(tmp_path)) if s[0] == "evaluator.dispatch"]
+    assert got == [(4, 0), (8, 1), (8, 0)]
 
 
 def test_traced_front_equals_untraced(searched):
