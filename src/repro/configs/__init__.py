@@ -26,6 +26,7 @@ _MODULES = {
     "seamless-m4t-medium": "seamless_m4t_medium",
     "xlstm-350m": "xlstm_350m",
     "sru_timit": "sru_timit",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

@@ -37,6 +37,15 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_d_conv: int = 4
     ssm_chunk: int = 256
+    # Mamba-2 (SSD) mixers: ``ssm_d_inner`` split over heads with one
+    # scalar decay each, B/C shared by the heads of each of
+    # ``ssm_n_groups`` groups; 0 heads is the Mamba-1 layout above
+    ssm_n_heads: int = 0
+    ssm_n_groups: int = 1
+
+    # --- Mamba-2/attention hybrid (granite-4.0-h): the layer indices that
+    # are attention, every other layer a Mamba-2 mixer ---
+    attn_layers: Tuple[int, ...] = ()
 
     # --- xLSTM ---
     slstm_every: int = 2             # 1 sLSTM per N blocks (rest mLSTM)
@@ -53,6 +62,12 @@ class ArchConfig:
     # --- misc ---
     head_dim_override: int = 0
     rope_theta: float = 10000.0
+    nope: bool = False               # no positional embedding (no RoPE)
+    # scalar multipliers (granitemoehybrid); the defaults change nothing
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0      # logits are divided by it
+    attention_multiplier: float = 0.0   # score scale; 0 -> 1/sqrt(head_dim)
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -75,6 +90,16 @@ class ArchConfig:
     @property
     def ssm_d_inner(self) -> int:
         return self.ssm_expand * self.d_model
+
+    @property
+    def ssm_head_dim(self) -> int:
+        """Mamba-2 head width: the inner width over the heads (0 without
+        Mamba-2 heads)."""
+        if not self.ssm_n_heads:
+            return 0
+        assert self.ssm_d_inner % self.ssm_n_heads == 0, (
+            self.ssm_d_inner, self.ssm_n_heads)
+        return self.ssm_d_inner // self.ssm_n_heads
 
     @property
     def moe_ff(self) -> int:
@@ -103,6 +128,14 @@ class ArchConfig:
             mamba = (D * 2 * di + di * self.ssm_d_conv + di * 2 * N
                      + di * N + di + di * D)
             core = n_attn * attn + n_mamba * mamba + L * block_ffn()
+        elif self.family == "mamba2_hybrid":
+            n_attn = len(self.attn_layers)
+            di, N = self.ssm_d_inner, self.ssm_d_state
+            gn = self.ssm_n_groups * N
+            mamba = (D * (2 * di + 2 * gn + self.ssm_n_heads)
+                     + (di + 2 * gn) * (self.ssm_d_conv + 1)
+                     + 3 * self.ssm_n_heads + di + di * D)
+            core = n_attn * attn + (L - n_attn) * mamba + L * dense_mlp
         elif self.family == "ssm":
             di = self.ssm_d_inner
             # mLSTM-ish block: qkv + gates + out

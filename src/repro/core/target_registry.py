@@ -8,9 +8,8 @@ decode step) is lane-independent along the population axis (C5, the jaxpr
 dataflow prover). Those checks need a *tiny but real* instance of each
 target: real params, real quant tables, shapes small enough that tracing is
 instant. A ``ContractHarness`` packages exactly that, and this registry
-maps architecture names to lazy harness builders so a future target (Mamba,
-direction 3 in the ROADMAP) inherits the whole gate by registering one
-function.
+maps architecture names to lazy harness builders so a new target inherits
+the whole gate by registering one function.
 
 Harness shape convention: every harness uses a time/sequence length of
 ``marker_dim`` (3) that appears in NO other dimension of the model — params,
@@ -56,6 +55,7 @@ class ContractHarness:
 _BUILTIN: Dict[str, str] = {
     "sru": "repro.core.sru_experiment:sru_contract_harness",
     "xlstm": "repro.core.xlstm_target:xlstm_contract_harness",
+    "granite_hybrid": "repro.core.granite_target:granite_contract_harness",
 }
 _CUSTOM: Dict[str, Callable[[], ContractHarness]] = {}
 

@@ -15,28 +15,15 @@ boundary): each searchable "layer" is one block's matmul weight set —
   ``s{g}``  sLSTM pair member g:  wx, r (recurrent kernel), wo
   ``head``  the LM head projection
 
-sharing one weight grid (MMSE clip per bit-width, pooled over the block's
-matrices — the Bi-SRU pools fwd/bwd the same way) and one activation grid
-calibrated at the block input (median of per-batch max-abs). Gate weights
-(wi/wf/fbias/bias), norms and the embedding table are not searched; they
-are counted as always-16-bit ``vector_weights`` for the memory/energy
-objectives, like the SRU's recurrent vectors.
-
-Per-layer quantized-weight banks: every quantizable leaf gets a
-``(|menu|, *leaf.shape)`` stack built by the identical jitted
-``fake_quant_triple`` expression (``Q.build_weight_bank``); the population
-forward gathers each lane's row by menu index (recovered from the qp grid
-tops via ``menu_index_from_hi``) instead of requantizing per lane — the
-same gather-don't-requantize contract the SRU banks established (PR 4).
-The sLSTM recurrent kernel ``r`` is the exception once a dispatch traces
-more lanes than the bank has rows (P > 4): it is not gathered, and each
-scan step contracts all lanes' states against the bank's rows and keeps
-each lane's own (``slstm_menu_engaged``, ``_menu_product``).
-
-Error metric: next-token top-1 error % on a bigram-structured synthetic LM
-task, MAX over 4 validation subsets (the paper's §4.2 ranking trick),
-exactly the convention the SRU target uses — so hardware feasibility
-margins behave identically.
+with the grids, banks, evaluator binding and error metric every LM target
+shares (``core/lm_target.py``). Gate weights (wi/wf/fbias/bias), norms and
+the embedding table are not searched; they are counted as always-16-bit
+``vector_weights`` for the memory/energy objectives, like the SRU's
+recurrent vectors. The sLSTM recurrent kernel ``r`` is the one leaf not
+gathered per lane once a dispatch traces more lanes than the bank has rows
+(P > 4): each scan step then contracts all lanes' states against the
+bank's rows and keeps each lane's own (``slstm_menu_engaged``,
+``_menu_product``).
 
 Determinism: every stochastic site is an explicit jax PRNG key or seeded
 synthetic-data stream; nothing touches ``np.random`` global state
@@ -54,7 +41,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import ArchConfig
-from repro.core import batched_eval
+from repro.core import lm_target as LM
 from repro.core import quantization as Q
 from repro.data import synthetic
 from repro.models import common as cm
@@ -197,11 +184,9 @@ def forward_population(params, cfg: ArchConfig, tokens, qp_stack,
                 return Q.menu_index_from_hi(row[li[name], 2])
 
             def get_w(name):
-                idx = idx_of(name)
-                with jax.named_scope("xlstm.weight_gather"):
-                    return {k: jnp.take(b, idx, axis=0)
-                            for k, b in banks[name].items()
-                            if not (menu_rec and k == "r")}
+                return LM.gather_rows(banks[name], idx_of(name),
+                                      "xlstm.weight_gather",
+                                      skip=("r",) if menu_rec else ())
 
             if menu_rec:
                 def get_rec(name):
@@ -213,91 +198,51 @@ def forward_population(params, cfg: ArchConfig, tokens, qp_stack,
 
 
 def calibrate(params, cfg: ArchConfig, token_batches) -> Dict[str, float]:
-    """Expected block-input activation ranges = median of per-batch
-    max-abs (the paper's calibration recipe)."""
-    cal = Q.ActRangeCalibrator()
-
-    def q_act(name, x):
-        cal.observe(name, x)
-        return x
-
-    for toks in token_batches:
-        forward(params, cfg, toks,
-                lambda name: _layer_leaves(params, cfg, name), q_act)
-    return cal.expected_ranges()
+    """Expected block-input activation ranges (``lm_target.calibrate``)."""
+    return LM.calibrate(
+        lambda toks, q_act: forward(
+            params, cfg, toks,
+            lambda name: _layer_leaves(params, cfg, name), q_act),
+        token_batches)
 
 
 def weight_grids(params, cfg: ArchConfig):
-    """(wclips, wranges): per-(layer, bits) MMSE clips pooled over the
-    block's matrices, and per-layer abs-max ranges for the 16-bit rows."""
-    wclips: Dict[Tuple[str, int], float] = {}
-    wranges: Dict[str, float] = {}
-    for name in quant_layer_names(cfg):
-        leaves = _layer_leaves(params, cfg, name)
-        flat = np.concatenate([np.asarray(v, np.float32).ravel()
-                               for v in leaves.values()])
-        wranges[name] = float(np.abs(flat).max())
-        for bits in (2, 4, 8):
-            wclips[(name, bits)] = Q.mmse_clip(flat, bits)
-    return wclips, wranges
+    """(wclips, wranges) pooled over each block's matrices
+    (``lm_target.weight_grids``)."""
+    return LM.weight_grids(lambda name: _layer_leaves(params, cfg, name),
+                           quant_layer_names(cfg))
 
 
 @dataclass
-class XLSTMTarget:
+class XLSTMTarget(LM.LMTarget):
     """``SearchTarget`` over a trained + calibrated registry xLSTM."""
-    cfg: ArchConfig
-    params: dict
-    val_subsets: list               # 4 x (tokens, next-token labels)
-    test_batches: list
-    act_ranges: Dict[str, float]
-    wclips: Dict[Tuple[str, int], float]
-    wranges: Dict[str, float]
-    baseline_val_error: float = 0.0
-    baseline_test_error: float = 0.0
 
     supports_retrain = True            # SearchTarget: beacons available
-
-    def __post_init__(self):
-        self.shared_error_memo: Dict[tuple, float] = {}
-        self._evaluators: Dict[tuple, batched_eval.PopulationEvaluator] = {}
-        self._qp_tables = None
-        cfg = self.cfg
-        self._plain = jax.jit(lambda p, t: forward_plain(p, cfg, t))
-        self._pop = jax.jit(
-            lambda p, t, stack: forward_population(p, cfg, t, stack))
-
-    # ---- search-space description ----
 
     @property
     def layer_names(self) -> Tuple[str, ...]:
         return quant_layer_names(self.cfg)
 
-    @property
-    def menu(self) -> Tuple[int, ...]:
-        return Q.SUPPORTED_BITS
+    def _leaves(self, params, name):
+        return _layer_leaves(params, self.cfg, name)
 
-    # ---- hardware-objective inputs ----
+    def _forward_plain(self, params, tokens):
+        return forward_plain(params, self.cfg, tokens)
 
-    @property
-    def layer_weights(self) -> Dict[str, int]:
-        return {name: sum(int(np.prod(v.shape)) for v in
-                          _layer_leaves(self.params, self.cfg, name).values())
-                for name in self.layer_names}
+    def _forward_population(self, params, tokens, qp_stack, banks):
+        return forward_population(params, self.cfg, tokens, qp_stack,
+                                  banks=banks)
 
-    @property
-    def layer_macs(self) -> Dict[str, int]:
-        """Per-token MACs == matmul weights per layer (each matrix weight
-        multiplies once per token, recurrent kernels once per step — the
-        same weights==MACs identity the SRU layers have)."""
-        return self.layer_weights
-
-    @property
-    def vector_weights(self) -> int:
-        """Everything outside the searchable matrices (embedding, norms,
-        gate weights, biases) — stored at 16 bits, never searched."""
-        total = sum(int(np.prod(np.shape(leaf)))
-                    for leaf in jax.tree.leaves(self.params))
-        return total - sum(self.layer_weights.values())
+    def _dispatch_stats(self, lanes, banks, shards=1):
+        """``slstm_menu``: 1 where the sLSTM recurrence of a shard's
+        ``lanes`` lanes contracts against the bank's menu rows;
+        ``gathered_bytes`` counts no copy of ``r`` there."""
+        menu = slstm_menu_engaged(banks, lanes)
+        skip = tuple((n, "r") for n, b in (banks or {}).items()
+                     if menu and "r" in b)
+        return {"slstm_menu": int(menu),
+                "gathered_bytes": LM.gathered_bytes(banks, lanes * shards,
+                                                    skip)}
 
     @property
     def fixed_ops(self) -> int:
@@ -336,113 +281,6 @@ class XLSTMTarget:
         """One-off binary-connect retrain under ``alloc`` (fresh stream)."""
         base = self.params if base_params is None else base_params
         return self.beacon_retrainer(steps)(alloc, base)
-
-    # ---- quantization-grid plumbing ----
-
-    def qp_for(self, alloc: Alloc):
-        qp = {}
-        for name, (wb, ab) in alloc.items():
-            wtrip = Q.quant_triple(
-                wb, self.wclips[(name, wb)] if wb != 16
-                else self.wranges[name])
-            atrip = Q.quant_triple(ab, self.act_ranges[name])
-            qp[name] = tuple(np.float32(v) for v in (wtrip + atrip))
-        return qp
-
-    def qp_menu_tables(self):
-        if self._qp_tables is None:
-            names = self.layer_names
-            K = len(Q.SUPPORTED_BITS)
-            w_t = np.empty((len(names), K, 3), np.float32)
-            a_t = np.empty((len(names), K, 3), np.float32)
-            for i, nm in enumerate(names):
-                for k, b in enumerate(Q.SUPPORTED_BITS):
-                    w_t[i, k] = Q.quant_triple(
-                        b, self.wranges[nm] if b == 16
-                        else self.wclips[(nm, b)])
-                    a_t[i, k] = Q.quant_triple(b, self.act_ranges[nm])
-            self._qp_tables = (w_t, a_t)
-        return self._qp_tables
-
-    def make_banks(self, params):
-        """Per-layer, per-leaf quantized-weight banks against this target's
-        frozen post-calibration grids (one build per parameter set)."""
-        banks = {}
-        for name in self.layer_names:
-            trips = Q.menu_triples(
-                Q.SUPPORTED_BITS,
-                lambda b, _n=name: (self.wranges[_n] if b == 16
-                                    else self.wclips[(_n, b)]))
-            banks[name] = {k: Q.build_weight_bank(w, trips)
-                           for k, w in
-                           _layer_leaves(params, self.cfg, name).items()}
-        return banks
-
-    # ---- error evaluation ----
-
-    def batched_evaluator(self, mesh=None, partition: str = "shard_map",
-                          use_banks: Optional[bool] = None
-                          ) -> batched_eval.PopulationEvaluator:
-        key = (mesh, partition if mesh is not None else "", use_banks)
-        if key not in self._evaluators:
-            cfg = self.cfg
-
-            def forward_pop(params, feats, qp_stack, banks):
-                return forward_population(params, cfg, feats, qp_stack,
-                                          banks=banks)
-
-            def dispatch_stats(lanes, banks):
-                return {"slstm_menu": int(slstm_menu_engaged(banks, lanes))}
-
-            self._evaluators[key] = batched_eval.PopulationEvaluator(
-                self.layer_names, self.val_subsets, self.qp_for,
-                forward_pop, mesh=mesh, partition=partition,
-                make_banks=self.make_banks, use_banks=use_banks,
-                qp_tables=self.qp_menu_tables(), menu_bits=self.menu,
-                dispatch_stats=dispatch_stats)
-        return self._evaluators[key]
-
-    def val_error_batch(self, allocs, params=None, *, mesh=None,
-                        partition: str = "shard_map",
-                        use_banks: Optional[bool] = None,
-                        bank_format: str = "f32") -> List[float]:
-        """Max-over-subsets next-token error % for every allocation in one
-        dispatch (generic evaluator: buckets, folding, banks, mesh). Only
-        f32 banks exist for this target; another ``bank_format`` raises."""
-        if bank_format != "f32":
-            raise ValueError(f"the xLSTM target has f32 banks only, not "
-                             f"bank_format={bank_format!r}")
-        params = self.params if params is None else params
-        return self.batched_evaluator(mesh=mesh, partition=partition,
-                                      use_banks=use_banks
-                                      ).errors(allocs, params)
-
-    def val_error(self, alloc: Optional[Alloc] = None,
-                  params=None) -> float:
-        params = self.params if params is None else params
-        if alloc is not None:
-            return self.val_error_batch([alloc], params=params)[0]
-        errs = []
-        for toks, labels in self.val_subsets:
-            logits = self._plain(params, toks)
-            e = int(jnp.sum(jnp.argmax(logits, -1) != labels))
-            errs.append(100.0 * e / labels.size)
-        return max(errs)
-
-    def test_error(self, alloc: Optional[Alloc] = None,
-                   params=None) -> float:
-        params = self.params if params is None else params
-        te = tn = 0
-        for toks, labels in self.test_batches:
-            if alloc is None:
-                logits = self._plain(params, toks)
-            else:
-                stack = jnp.asarray(batched_eval.stack_qps(
-                    [self.qp_for(alloc)], list(self.layer_names)))
-                logits = self._pop(params, toks, stack)[0]
-            te += int(jnp.sum(jnp.argmax(logits, -1) != labels))
-            tn += labels.size
-        return 100.0 * te / tn
 
 
 # ------------------------------------------------------------- training

@@ -69,12 +69,12 @@ def rope(x, positions, theta: float = 10000.0):
 
 # ---------------------------------------------------------------- attention
 
-def _attn_block(q, k, v, qpos, kpos, kv_valid):
+def _attn_block(q, k, v, qpos, kpos, kv_valid, scale=None):
     """Full (non-chunked) GQA attention for one block. q:(B,Tq,KV,G,d),
     k/v:(B,Tk,KV,d). Returns (B,Tq,KV,G,d) in f32."""
     scores = jnp.einsum("btkgd,bskd->bkgts", q, k,
                         preferred_element_type=jnp.float32)
-    scores *= 1.0 / math.sqrt(q.shape[-1])
+    scores *= 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     mask = kpos[None, :] <= qpos[:, None]                    # (Tq,Tk) causal
     if kv_valid is not None:
         mask = mask & (kpos[None, :] < kv_valid)
@@ -89,11 +89,13 @@ DENSE_ATTN_MAX = 8192   # up to here, materialize scores (differentiable path)
 
 def gqa_attention(q, k, v, *, q_offset=0, kv_valid=None,
                   chunk_q: int = 512, chunk_k: int = 1024,
-                  causal: bool = True, dense_max: Optional[int] = None):
+                  causal: bool = True, dense_max: Optional[int] = None,
+                  scale: Optional[float] = None):
     """Memory-safe GQA attention.
 
     q: (B, Tq, H, d); k, v: (B, Tk, KV, d). Grouped so each of KV kv-heads
-    serves G = H // KV query heads.
+    serves G = H // KV query heads. Scores are scaled by ``scale``, by
+    default 1/sqrt(d).
 
     Two regimes:
     - T <= DENSE_ATTN_MAX: materialized scores. Used for training — the
@@ -116,7 +118,7 @@ def gqa_attention(q, k, v, *, q_offset=0, kv_valid=None,
         kpos = jnp.arange(Tk)
         if not causal:
             qpos = jnp.full((Tq,), Tk)      # everything visible
-        o = _attn_block(qg, k, v, qpos, kpos, kv_valid)
+        o = _attn_block(qg, k, v, qpos, kpos, kv_valid, scale)
         return o.reshape(B, Tq, H, d).astype(q.dtype)
 
     # pad Tq/Tk to chunk multiples
@@ -135,7 +137,7 @@ def gqa_attention(q, k, v, *, q_offset=0, kv_valid=None,
     qc = qg.reshape(B, nq, chunk_q, KV, G, d).transpose(1, 0, 2, 3, 4, 5)
     kc = k.reshape(B, nk, chunk_k, KV, d).transpose(1, 0, 2, 3, 4)
     vc = v.reshape(B, nk, chunk_k, KV, d).transpose(1, 0, 2, 3, 4)
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
 
     def q_step(_, qi_qchunk):
         qi, qchunk = qi_qchunk
@@ -196,15 +198,18 @@ ATTN_AXES = {
 }
 
 
-def attn_qkv(p, x, positions, theta):
+def attn_qkv(p, x, positions, theta, nope: bool = False):
+    """q, k, v projections; RoPE on q and k unless ``nope``."""
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"],
                    preferred_element_type=acc_dtype()).astype(x.dtype)
     k = jnp.einsum("btd,dhk->bthk", x, p["wk"],
                    preferred_element_type=acc_dtype()).astype(x.dtype)
     v = jnp.einsum("btd,dhk->bthk", x, p["wv"],
                    preferred_element_type=acc_dtype()).astype(x.dtype)
-    q = shard(rope(q, positions, theta), "batch", "seq", "heads", None)
-    k = shard(rope(k, positions, theta), "batch", "seq", "kv_heads", None)
+    if not nope:
+        q, k = rope(q, positions, theta), rope(k, positions, theta)
+    q = shard(q, "batch", "seq", "heads", None)
+    k = shard(k, "batch", "seq", "kv_heads", None)
     v = shard(v, "batch", "seq", "kv_heads", None)
     return q, k, v
 
