@@ -157,11 +157,23 @@ class PopulationEvaluator:
     shard (like params) and the row gather runs inside each shard's
     program, so single-device behaviour and error counts are unchanged.
 
-    ``dispatch_stats`` (optional): ``(lanes, banks) -> {stat: int}``, more
-    stats for each dispatch's ``evaluator.dispatch`` profiler span, given
-    the lane count ``forward_pop`` traces (per shard under ``shard_map``)
-    and the banks it is passed (the xLSTM target records which sLSTM
-    recurrence its forward takes).
+    ``skips_padding``: ``forward_pop`` takes two more arguments, ``live``
+    ((P,) bool, false on the padding lanes) and ``lane_out`` (one lane's
+    logits -> that lane's wrong counts, so that a forward running lanes in
+    turn never holds every lane's logits), returns each lane's counts, and
+    computes no lane whose ``live`` is false (its counts are zeros). The
+    granite target runs its lanes one at a time, so a padding lane skipped
+    is device time saved; a forward that batches its lanes ignores the
+    mask. Each dispatch's ``evaluator.dispatch`` span records
+    ``lanes_computed``: the real lanes where the forward skips padding,
+    else the whole padded bucket.
+
+    ``dispatch_stats`` (optional): ``(traced, computed, banks) -> {stat:
+    int}``, more stats for that span, given the lane count ``forward_pop``
+    traces (per shard under ``shard_map``), ``lanes_computed`` and the
+    banks it is passed (the LM targets record the weight copies their
+    computed lanes gather, and the xLSTM target which sLSTM recurrence its
+    forward takes).
     """
 
     def __init__(self, layer_names, val_subsets,
@@ -176,7 +188,8 @@ class PopulationEvaluator:
                  menu_bits=None,
                  bank_format: str = "f32",
                  make_packed_banks: Optional[Callable] = None,
-                 dispatch_stats: Optional[Callable] = None):
+                 dispatch_stats: Optional[Callable] = None,
+                 skips_padding: bool = False):
         from repro.core import quantization as Q
 
         self.layer_names = list(layer_names)
@@ -218,6 +231,7 @@ class PopulationEvaluator:
         self._make_packed_banks = make_packed_banks
         self._extend_banks = extend_banks
         self._dispatch_stats = dispatch_stats
+        self._skips_padding = skips_padding
         # banks keyed by parameter-set identity; the params ref is kept so
         # a collected object's id can never alias a live cache entry
         self._banks: Dict[int, tuple] = {}
@@ -238,18 +252,27 @@ class PopulationEvaluator:
 
         # the per-generation dispatch: bank gather (or requant) -> model
         # population forward -> frame-error reduction to integer counts,
-        # one jitted call per (bucket, subset-shape). The qp grid stack is
-        # the only buffer consumed per call, so it is donated where the
-        # backend supports aliasing (not CPU).
-        def _batch_err(params, banks, feats, labels, qp_stack):
-            logits = forward_pop(params, feats, qp_stack, banks)
-            with jax.named_scope("eval.count"):
-                wrong = jnp.argmax(logits, -1) != labels[None]  # (P,B*,T)
-                if self._folded:
-                    p, _, t = wrong.shape
-                    return jnp.sum(wrong.reshape(p, n_sub, -1, t),
-                                   axis=(2, 3))
-                return jnp.sum(wrong, axis=(1, 2))
+        # one jitted call per (bucket, subset-shape). ``lanes`` is the
+        # (qp grid stack, live mask) pair of ``_stack`` (a bare qp stack
+        # scores every lane); it is the only input consumed per call, so
+        # it is donated where the backend supports aliasing (not CPU).
+        def _batch_err(params, banks, feats, labels, lanes):
+            qp_stack, live = lanes if isinstance(lanes, tuple) \
+                else (lanes, None)
+
+            def count(logits):
+                with jax.named_scope("eval.count"):
+                    wrong = jnp.argmax(logits, -1) != labels[None]
+                    if self._folded:                        # (P,B*,T)
+                        p, _, t = wrong.shape
+                        return jnp.sum(wrong.reshape(p, n_sub, -1, t),
+                                       axis=(2, 3))
+                    return jnp.sum(wrong, axis=(1, 2))
+
+            if skips_padding:
+                return forward_pop(params, feats, qp_stack, banks, live,
+                                   lambda logits: count(logits[None])[0])
+            return count(forward_pop(params, feats, qp_stack, banks))
 
         self._batch_err_fn = _batch_err
         self._pop_axis = pop_axis
@@ -284,10 +307,10 @@ class PopulationEvaluator:
             if self._partition == "gspmd":
                 # activate the "pop" logical-axis rule so the constraints
                 # inside forward_population bind to this mesh at trace time
-                def call(params, banks, feats, labels, qp_stack,
+                def call(params, banks, feats, labels, lanes,
                          _f=sharded, _m=mesh):
                     with dist_sharding.axis_rules(_m):
-                        return _f(params, banks, feats, labels, qp_stack)
+                        return _f(params, banks, feats, labels, lanes)
                 self._batch_err = call
             else:
                 self._batch_err = sharded
@@ -314,7 +337,11 @@ class PopulationEvaluator:
             self._banks[key] = (params, banks)
         return self._banks[key][1]
 
-    def _stack(self, allocs: Sequence[Alloc]) -> np.ndarray:
+    def _stack(self, allocs: Sequence[Alloc]
+               ) -> Tuple[np.ndarray, np.ndarray]:
+        """The dispatch's per-lane input: the (B, L, 6) qp grid stack
+        padded to the compile bucket B by repeating the last candidate,
+        and the (B,) mask ``live`` that is false on the padding lanes."""
         if self.use_banks and self._qp_tables is not None:
             # menu indexing: gather the per-layer triple rows directly
             w_t, a_t = self._qp_tables
@@ -333,34 +360,35 @@ class PopulationEvaluator:
         pad = target - len(allocs)
         if pad:
             stack = np.concatenate([stack, np.repeat(stack[-1:], pad, 0)])
-        return stack
+        return stack, np.arange(target) < len(allocs)
 
-    def _dispatch(self, params, banks, feats, labels, stack):
+    def _dispatch(self, params, banks, feats, labels, lanes):
         """The single jitted dispatch, with the fault-injection hook in
         front. With ``faults=None`` this is exactly one ``_batch_err``
         call — the C4 one-dispatch-per-generation contract."""
         if self.faults is not None:
             self.faults.on_dispatch(self)
-        return self._batch_err(params, banks, feats, labels, stack)
+        return self._batch_err(params, banks, feats, labels, lanes)
 
-    def _subset_errors(self, params, banks, feats, labels, stack, p: int,
+    def _subset_errors(self, params, banks, feats, labels, lanes, p: int,
                        frames: int) -> np.ndarray:
         """One dispatch and its readback; returns the real lanes' error %
         per subset. Three profiler spans split the host's part: the
-        enqueue (``evaluator.dispatch``, with the real and padded lane
-        counts), the wait for the device, and the readback with the
-        count-to-error% math. The copy to the host is queued with the
-        program, as ``jax.device_get`` queues it, so waiting apart from the
-        readback costs no second round trip to the device."""
-        bucket = int(stack.shape[0])
-        stats = {}
+        enqueue (``evaluator.dispatch``, with the real, padded and
+        computed lane counts), the wait for the device, and the readback
+        with the count-to-error% math. The copy to the host is queued with
+        the program, as ``jax.device_get`` queues it, so waiting apart from
+        the readback costs no second round trip to the device."""
+        bucket = int(lanes[0].shape[0])
+        computed = p if self._skips_padding else bucket
+        stats = {"lanes_computed": computed}
         if self._dispatch_stats is not None:
             traced = (bucket // self._n_shards
                       if self._partition == "shard_map" else bucket)
-            stats = self._dispatch_stats(traced, banks)
+            stats.update(self._dispatch_stats(traced, computed, banks))
         with TraceAnnotation("evaluator.dispatch", lanes=p, bucket=bucket,
                              **stats):
-            out = self._dispatch(params, banks, feats, labels, stack)
+            out = self._dispatch(params, banks, feats, labels, lanes)
             if isinstance(out, jax.Array):
                 out.copy_to_host_async()
         with TraceAnnotation("evaluator.wait"):
@@ -373,16 +401,16 @@ class PopulationEvaluator:
         """One attempt at scoring a generation; returns the (P,) float
         max-over-subsets error array (real lanes only, padding sliced)."""
         with TraceAnnotation("evaluator.stack"):
-            stack = self._stack(allocs)
+            lanes = self._stack(allocs)
         banks = self._banks_for(params)
         p = len(allocs)
         if self._folded:
             errs = np.max(self._subset_errors(
-                params, banks, self._feats_all, self._labels_all, stack, p,
+                params, banks, self._feats_all, self._labels_all, lanes, p,
                 self._subset_frames), axis=1)                # (P, S) -> (P,)
         else:
             errs = np.max(np.stack([
-                self._subset_errors(params, banks, feats, labels, stack, p,
+                self._subset_errors(params, banks, feats, labels, lanes, p,
                                     int(np.asarray(labels).size))
                 for feats, labels in self.val_subsets]), axis=0)
         if self.faults is not None:

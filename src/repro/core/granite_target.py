@@ -25,13 +25,17 @@ activation grid at the layer's normed input):
 Conv weights and biases, A_log, D, dt_bias and the norms are
 ``vector_weights`` at 16 bits.
 
-Banked lane: each lane's copy of a layer's leaves is gathered from the
-bank only once the layer's input exists (an ``optimization_barrier`` ties
-the gather to it), so one layer's copies are live at a time and not the
-whole model's for every lane. The embedding lookup reads the lane's rows of
-the head's bank directly, without a copy of the table. Every matmul reads
-each weight for all of a lane's tokens, so a gathered copy costs far less
-than contracting against all 4 menu rows (4x the FLOPs). Named scopes:
+Population forward: the lanes run one at a time (``lax.map``), and a lane
+that the evaluator marks as padding is skipped, not computed. A lane has
+no per-token recurrence to batch across lanes (two SSD chunks, and
+matmuls over all of its tokens that fill the MXU alone), so running lanes
+in turn costs no speed, and a skipped padding lane saves its whole
+forward. Banked lane: each layer's leaves are gathered from the bank as a
+copy of the lane's row; only one lane's copies exist at a time. The
+embedding lookup reads the lane's rows of the head's bank directly,
+without a copy of the table. Every matmul reads each weight for all of a
+lane's tokens, so a gathered copy costs far less than contracting
+against all 4 menu rows (4x the FLOPs). Named scopes:
 ``granite.mamba2`` (``granite.ssd_chunk`` and ``granite.ssd_state`` inside
 it), ``granite.attention``, ``granite.mlp``, ``granite.head`` and
 ``granite.weight_gather``.
@@ -138,9 +142,9 @@ def _residual(h, out, cfg: ArchConfig):
 
 
 def forward(params, cfg: ArchConfig, tokens, get_w, q_act, lookup):
-    """The hybrid forward with quantization hooks: ``get_w(name, x)`` ->
-    the layer's (quantized) searchable leaves, given its input ``x``;
-    ``q_act(name, x)`` -> the layer's (fake-quantized) normed input;
+    """The hybrid forward with quantization hooks: ``get_w(name)`` -> the
+    layer's (quantized) searchable leaves; ``q_act(name, x)`` -> the
+    layer's (fake-quantized) normed input;
     ``lookup(tokens)`` -> the rows of the (quantized) table. The
     activation stream has the table's type (bf16 as served). Returns f32
     logits (B, T, vocab)."""
@@ -149,7 +153,7 @@ def forward(params, cfg: ArchConfig, tokens, get_w, q_act, lookup):
     for i, layer in enumerate(params["layers"]):
         mix, mlp = f"mix{i}", f"mlp{i}"
         x = q_act(mix, cm.rms_norm(h, layer["norm_mix"], cfg.norm_eps))
-        w = {**layer["mixer"], **get_w(mix, x)}
+        w = {**layer["mixer"], **get_w(mix)}
         if mixer_kind(cfg, i) == "attention":
             with jax.named_scope("granite.attention"):
                 out = attention(w, cfg, x)
@@ -158,12 +162,12 @@ def forward(params, cfg: ArchConfig, tokens, get_w, q_act, lookup):
                 out = mamba2.mamba2_mixer(w, cfg, x)
         h = _residual(h, out, cfg)
         x = q_act(mlp, cm.rms_norm(h, layer["norm_mlp"], cfg.norm_eps))
-        w = get_w(mlp, x)
+        w = get_w(mlp)
         with jax.named_scope("granite.mlp"):
             h = _residual(h, cm.mlp(w, x), cfg)
     with jax.named_scope("granite.head"):
         x = q_act("head", cm.rms_norm(h, params["final_norm"], cfg.norm_eps))
-        table = get_w("head", x)["embed"]
+        table = get_w("head")["embed"]
         logits = jnp.einsum("btd,vd->btv", x, table,
                             preferred_element_type=F32)
         return logits / cfg.logits_scaling
@@ -172,21 +176,25 @@ def forward(params, cfg: ArchConfig, tokens, get_w, q_act, lookup):
 def forward_plain(params, cfg: ArchConfig, tokens):
     """Full-precision forward (identity hooks) — the baseline path."""
     return forward(params, cfg, tokens,
-                   lambda name, x: _layer_leaves(params, cfg, name),
+                   lambda name: _layer_leaves(params, cfg, name),
                    lambda name, x: x,
                    lambda toks: jnp.take(params["embed"], toks, axis=0))
 
 
 def forward_population(params, cfg: ArchConfig, tokens, qp_stack,
-                       banks=None):
-    """Score P quantization candidates in one call: vmap of the hooked
-    forward over the (P, L, 6) qp grid stack. With ``banks`` each lane's
-    leaves are gathered by menu index one layer at a time (module
-    docstring) and the lookup reads the head's bank; rows are built by the
+                       banks=None, live=None, lane_out=None):
+    """Score P quantization candidates in one call, one lane at a time
+    (``lax.map`` over the (P, L, 6) qp grid stack; module docstring). With
+    ``banks`` each lane's leaves are gathered by menu index one layer at a
+    time and the lookup reads the head's bank; rows are built by the
     identical ``fake_quant_triple`` expression, so the gather lane matches
-    the requant lane exactly."""
+    the requant lane exactly. ``live`` ((P,) bool, all true by default):
+    a lane where it is false is not computed and returns zeros.
+    ``lane_out`` maps a lane's logits to what the lane returns (the logits
+    by default), inside the lane."""
     names = quant_layer_names(cfg)
     li = {n: i for i, n in enumerate(names)}
+    lane_out = lane_out or (lambda logits: logits)
 
     def one(row):                                   # (L, 6) per lane
         def q_act(name, x):
@@ -194,21 +202,20 @@ def forward_population(params, cfg: ArchConfig, tokens, qp_stack,
             return Q.fake_quant_triple(x, r[3], r[4], r[5], use_ste=False)
 
         if banks is None:
-            def get_w(name, x):
+            def get_w(name):
                 r = row[li[name]]
                 return {k: Q.fake_quant_triple(w, r[0], r[1], r[2],
                                                use_ste=False)
                         for k, w in _layer_leaves(params, cfg, name).items()}
 
             def lookup(toks):
-                return jnp.take(get_w("head", None)["embed"], toks, axis=0)
+                return jnp.take(get_w("head")["embed"], toks, axis=0)
         else:
             def idx_of(name):
                 return Q.menu_index_from_hi(row[li[name], 2])
 
-            def get_w(name, x):
-                _, idx = jax.lax.optimization_barrier((x, idx_of(name)))
-                return LM.gather_rows(banks[name], idx,
+            def get_w(name):
+                return LM.gather_rows(banks[name], idx_of(name),
                                       "granite.weight_gather")
 
             def lookup(toks):
@@ -218,14 +225,26 @@ def forward_population(params, cfg: ArchConfig, tokens, qp_stack,
                     return jnp.take(bank.reshape(K * V, D),
                                     idx_of("head") * V + toks, axis=0)
 
-        return forward(params, cfg, tokens, get_w, q_act, lookup)
+        return lane_out(forward(params, cfg, tokens, get_w, q_act, lookup))
 
-    return jax.vmap(one)(qp_stack)
+    out = jax.eval_shape(lane_out, jax.ShapeDtypeStruct(
+        tokens.shape + (cfg.vocab_size,), F32))
+
+    def skip(row):
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), out)
+
+    if live is None:
+        live = jnp.ones(qp_stack.shape[:1], bool)
+    return jax.lax.map(lambda lane: jax.lax.cond(lane[1], one, skip,
+                                                 lane[0]),
+                       (qp_stack, live))
 
 
 @dataclass
 class GraniteTarget(LM.LMTarget):
     """``SearchTarget`` over a calibrated granite-4.0-h hybrid."""
+
+    skips_padding = True            # lanes run in turn (forward_population)
 
     @property
     def layer_names(self) -> Tuple[str, ...]:
@@ -237,9 +256,10 @@ class GraniteTarget(LM.LMTarget):
     def _forward_plain(self, params, tokens):
         return forward_plain(params, self.cfg, tokens)
 
-    def _forward_population(self, params, tokens, qp_stack, banks):
+    def _forward_population(self, params, tokens, qp_stack, banks,
+                            live=None, lane_out=None):
         return forward_population(params, self.cfg, tokens, qp_stack,
-                                  banks=banks)
+                                  banks=banks, live=live, lane_out=lane_out)
 
     @property
     def fixed_ops(self) -> int:
@@ -264,7 +284,7 @@ def make_target(params, cfg: ArchConfig, val_subsets, test_batches=()
             return x
 
         logits = forward(params, cfg, toks,
-                         lambda name, x: _layer_leaves(params, cfg, name),
+                         lambda name: _layer_leaves(params, cfg, name),
                          q_act, lambda t: jnp.take(params["embed"], t, axis=0))
         return out, jnp.argmax(logits, -1)
 

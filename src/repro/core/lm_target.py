@@ -32,7 +32,6 @@ import numpy as np
 
 from repro.core import batched_eval
 from repro.core import quantization as Q
-from repro.distributed import pop_sharding
 
 Alloc = Dict[str, Tuple[int, int]]
 
@@ -103,6 +102,9 @@ class LMTarget:
     baseline_test_error: float = 0.0
 
     supports_retrain = False
+    # whether ``_forward_population`` takes the evaluator's live mask and
+    # computes no padding lane (``PopulationEvaluator``'s skips_padding)
+    skips_padding = False
 
     def __post_init__(self):
         self.shared_error_memo: Dict[tuple, float] = {}
@@ -129,13 +131,13 @@ class LMTarget:
     def _forward_population(self, params, tokens, qp_stack, banks):
         raise NotImplementedError
 
-    def _dispatch_stats(self, lanes: int, banks,
-                        shards: int = 1) -> Dict[str, int]:
+    def _dispatch_stats(self, traced: int, computed: int,
+                        banks) -> Dict[str, int]:
         """Stats of the ``evaluator.dispatch`` span of a bucket program
-        that traces ``lanes`` lanes on each of ``shards`` population
-        shards: ``gathered_bytes``, the per-lane weight copies of the
-        whole bucket."""
-        return {"gathered_bytes": gathered_bytes(banks, lanes * shards)}
+        that traces ``traced`` lanes a shard and computes ``computed``
+        lanes in all: ``gathered_bytes``, the per-lane weight copies of
+        the computed lanes."""
+        return {"gathered_bytes": gathered_bytes(banks, computed)}
 
     # ---- search-space description ----
 
@@ -221,16 +223,13 @@ class LMTarget:
                           ) -> batched_eval.PopulationEvaluator:
         key = (mesh, partition if mesh is not None else "", use_banks)
         if key not in self._evaluators:
-            # under shard_map the evaluator passes the lanes of one shard
-            shards = (pop_sharding.pop_axis_size(mesh)
-                      if partition == "shard_map" else 1)
             self._evaluators[key] = batched_eval.PopulationEvaluator(
                 self.layer_names, self.val_subsets, self.qp_for,
                 self._forward_population, mesh=mesh, partition=partition,
                 make_banks=self.make_banks, use_banks=use_banks,
                 qp_tables=self.qp_menu_tables(), menu_bits=self.menu,
-                dispatch_stats=lambda lanes, banks: self._dispatch_stats(
-                    lanes, banks, shards))
+                dispatch_stats=self._dispatch_stats,
+                skips_padding=self.skips_padding)
         return self._evaluators[key]
 
     def val_error_batch(self, allocs, params=None, *, mesh=None,

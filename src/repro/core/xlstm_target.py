@@ -233,16 +233,15 @@ class XLSTMTarget(LM.LMTarget):
         return forward_population(params, self.cfg, tokens, qp_stack,
                                   banks=banks)
 
-    def _dispatch_stats(self, lanes, banks, shards=1):
+    def _dispatch_stats(self, traced, computed, banks):
         """``slstm_menu``: 1 where the sLSTM recurrence of a shard's
-        ``lanes`` lanes contracts against the bank's menu rows;
+        ``traced`` lanes contracts against the bank's menu rows;
         ``gathered_bytes`` counts no copy of ``r`` there."""
-        menu = slstm_menu_engaged(banks, lanes)
+        menu = slstm_menu_engaged(banks, traced)
         skip = tuple((n, "r") for n, b in (banks or {}).items()
                      if menu and "r" in b)
         return {"slstm_menu": int(menu),
-                "gathered_bytes": LM.gathered_bytes(banks, lanes * shards,
-                                                    skip)}
+                "gathered_bytes": LM.gathered_bytes(banks, computed, skip)}
 
     @property
     def fixed_ops(self) -> int:

@@ -641,6 +641,98 @@ def test_c5_fails_on_cross_lane_normalization(sru_harness):
         [f.format() for f in c5]
 
 
+def _lane_proof(fn, qp_stack):
+    import jax
+
+    from tools.analysis import dataflow as df
+    return df.prove_lane_independence(jax.make_jaxpr(fn)(qp_stack), [0])
+
+
+def test_c5_accepts_lax_map_over_population():
+    """``lax.map`` over the population axis (a scan with no carry, lane-
+    shared consts, the stack sliced by lane, as the granite forward runs
+    its lanes) is lane-independent; its ys stack on axis 0."""
+    import jax
+    import jax.numpy as jnp
+
+    w = jnp.ones((6, 5))
+    live = jnp.asarray([True, False, True, True])
+
+    def lanes(qp):
+        return jax.lax.map(
+            lambda lane: jax.lax.cond(lane[1], lambda r: jnp.tanh(r @ w),
+                                      lambda r: jnp.zeros((3, 5)), lane[0]),
+            (qp, live))
+
+    rep = _lane_proof(lanes, jnp.ones((4, 3, 6)))
+    assert rep.ok and rep.out_axes == [0], [v.format()
+                                            for v in rep.violations]
+
+
+def test_c5_rejects_scan_over_population_with_a_carry():
+    """A scan over the population axis whose carry chains lane i into
+    lane i+1 (a running sum of the lanes) mixes lanes."""
+    import jax
+    import jax.numpy as jnp
+
+    def chained(qp):
+        def step(c, row):
+            c = c + row
+            return c, c
+        return jax.lax.scan(step, jnp.zeros((3, 6)), qp)[1]
+
+    rep = _lane_proof(chained, jnp.ones((4, 3, 6)))
+    assert not rep.ok
+    assert any(v.primitive == "scan" and "carry" in v.reason
+               for v in rep.violations)
+
+
+def test_c5_rejects_lax_map_closing_over_the_qp_stack():
+    """A ``lax.map`` whose body reads the whole tainted qp stack (here its
+    mean over lanes) lets step i read every lane."""
+    import jax
+    import jax.numpy as jnp
+
+    def closes_over(qp):
+        return jax.lax.map(lambda row: row + jnp.sum(qp, axis=0) * 0.0, qp)
+
+    rep = _lane_proof(closes_over, jnp.ones((4, 3, 6)))
+    assert not rep.ok
+    assert any(v.primitive == "scan" and "const" in v.reason
+               for v in rep.violations)
+
+
+@pytest.mark.parametrize("name", ["sru", "xlstm", "granite_hybrid"])
+def test_contracts_hold_on_every_harness(name):
+    """C1-C5 on each registered target's harness, after the lane-map
+    refinement of the scan rule (the granite forward is a ``lax.map``)."""
+    from tools.analysis.contracts import run_contracts
+    assert run_contracts([name]) == []
+
+
+@pytest.mark.parametrize("name", ["sru", "xlstm", "granite_hybrid"])
+def test_flipped_output_fails_c5_on_every_harness(name):
+    """The detector stays live on each harness: the banked forward with
+    its output flipped along the population axis fails the proof."""
+    import jax
+
+    from repro.core import batched_eval
+    from repro.core.target_registry import get_contract_harness
+    from tools.analysis.contracts import _contract_allocs
+
+    h = get_contract_harness(name)
+    t = h.target
+    qp_stack = batched_eval.stack_qps(
+        [t.qp_for(a) for a in _contract_allocs(h.layer_names, t.menu)],
+        list(h.layer_names))
+    banks = t.make_banks(t.params)
+    rep = _lane_proof(lambda qp: jax.tree_util.tree_map(
+        lambda o: o[::-1], h.forward_pop(t.params, h.feats, qp, banks)),
+        qp_stack)
+    assert not rep.ok
+    assert any(v.primitive == "rev" for v in rep.violations)
+
+
 def test_contract_registry_lists_both_targets():
     from repro.core import target_registry as tr
     assert {"sru", "xlstm"} <= set(tr.list_contract_targets())

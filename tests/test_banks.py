@@ -111,7 +111,7 @@ class TestMenuIndexing:
         names = list(trained.cfg.layer_names())
         ref = BE.stack_qps([trained.qp_for(a) for a in allocs], names)
         ev = trained.batched_evaluator(use_banks=True)
-        fast = ev._stack(allocs)[:len(allocs)]
+        fast = ev._stack(allocs)[0][:len(allocs)]
         assert np.array_equal(ref, fast)
 
 

@@ -178,11 +178,81 @@ def test_search_session_returns_front(bf16_target):
 
 
 def test_dispatch_records_gathered_bytes(bf16_target):
-    """``gathered_bytes``: one bank row a lane for every leaf."""
+    """``gathered_bytes``: one bank row a lane for every leaf, for the
+    lanes the program computes (11 real lanes of a 16-lane bucket)."""
     t = bf16_target
     banks = t.make_banks(t.params)
     per_lane = sum(2 * n for n in t.layer_weights.values())   # bf16 rows
-    assert t._dispatch_stats(16, banks) == {"gathered_bytes": 16 * per_lane}
+    assert t._dispatch_stats(16, 11, banks) == {
+        "gathered_bytes": 11 * per_lane}
+
+
+@pytest.mark.parametrize("P,bucket", [(3, 4), (5, 8)])
+def test_padded_dispatch_skips_padding_lanes(f32_target, monkeypatch, P,
+                                             bucket):
+    """P allocations padded to a larger bucket, through the evaluator:
+    every real lane's per-subset counts equal those of its allocation
+    scored alone and those of the plain reference (float32, as in
+    ``test_banked_population_matches_reference``); the padding lanes are
+    not computed and return zeros; the dispatch's span records the real
+    lanes as ``lanes_computed`` and gathers their copies only."""
+    t = f32_target
+    ev = t.batched_evaluator(use_banks=True)
+    banks = ev._banks_for(t.params)
+    spans = []
+
+    class Span:
+        def __init__(self, name, **stats):
+            spans.append((name, stats))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(batched_eval, "TraceAnnotation", Span)
+    allocs = _allocs(t.layer_names, P, seed=20 + P)
+
+    def counts(allocs):
+        lanes = ev._stack(allocs)
+        return np.asarray(ev._batch_err(t.params, banks, ev._feats_all,
+                                        ev._labels_all, lanes)), lanes
+
+    got, (stack, live) = counts(allocs)
+    assert got.shape == (bucket, len(t.val_subsets))
+    assert live.tolist() == [True] * P + [False] * (bucket - P)
+    assert not got[P:].any()                       # the skip branch's zeros
+    for i, a in enumerate(allocs):
+        alone, _ = counts([a])
+        assert got[i].tolist() == alone[0].tolist(), i
+        want = [int(jnp.sum(jnp.argmax(reference(t.params, t.cfg, toks,
+                                                 qp=stack[i]), -1)
+                            != labels))
+                for toks, labels in t.val_subsets]
+        assert got[i].tolist() == want, i
+
+    errs = ev.errors(allocs, t.params)
+    assert errs == [max(100.0 * c / ev._subset_frames for c in row)
+                    for row in got[:P].tolist()]
+    stats = [st for name, st in spans if name == "evaluator.dispatch"][-1]
+    per_lane = sum(4 * n for n in t.layer_weights.values())   # f32 rows
+    assert (stats["lanes"], stats["bucket"], stats["lanes_computed"],
+            stats["gathered_bytes"]) == (P, bucket, P, P * per_lane)
+
+
+def test_skipped_lane_returns_zeros(bf16_target):
+    """At the forward: a lane whose ``live`` is false returns zero logits,
+    and every other lane is bit for bit the lane of an all-live call."""
+    t = bf16_target
+    stack = _stack(t, _allocs(t.layer_names, 4, seed=31))
+    toks = t.val_subsets[0][0]
+    banks = t.make_banks(t.params)
+    every = population(t.params, t.cfg, toks, stack, banks=banks)
+    live = jnp.asarray([True, False, True, False])
+    some = population(t.params, t.cfg, toks, stack, banks=banks, live=live)
+    assert bool(jnp.all(some[::2] == every[::2]))
+    assert not bool(jnp.any(some[1::2]))
 
 
 def test_published_config_and_benchmark_cut():

@@ -279,9 +279,9 @@ def check_harness(h) -> List[Finding]:
     calls: List[int] = []
     real = ev._batch_err
 
-    def counting_stub(params, banks, feats, labels, qp_stack):
+    def counting_stub(params, banks, feats, labels, lanes):
         calls.append(1)
-        return np.zeros((qp_stack.shape[0], ev._n_subsets), np.int32)
+        return np.zeros((lanes[0].shape[0], ev._n_subsets), np.int32)
 
     try:
         ev._batch_err = counting_stub

@@ -28,8 +28,10 @@ per-primitive axis-transfer rules:
   gather-don't-requantize idiom; lane-shared indices selecting *from* the
   population axis are a mix;
 - ``scan``/``while``/``cond``/``pjit``/``custom_jvp_call`` recurse into
-  their sub-jaxprs (scan carries run to a taint fixpoint; scanning *over*
-  the population axis FAILS);
+  their sub-jaxprs (scan carries run to a taint fixpoint); scanning *over*
+  the population axis passes only as a lane map (``lax.map``: no carry,
+  lane-shared consts, every xs lane-shared or on axis 0, so step i reads
+  lane i alone) and FAILS otherwise;
 - ``pallas_call`` and any primitive without a rule FAIL CLOSED when a
   tainted operand reaches them — an unknown op is an unproven op.
 
@@ -501,10 +503,18 @@ class _Engine:
         nc, nk = p["num_consts"], p["num_carry"]
         inner = self._closed(p["jaxpr"])
         consts, carry, xs = ins[:nc], list(ins[nc:nc + nk]), ins[nc + nk:]
-        for i, a in enumerate(xs):
-            if a == 0:
-                raise _Mix("scan iterates OVER the population axis (the "
-                           "carry chains lane i into lane i+1)")
+        if 0 in xs:
+            # a scan OVER the population axis is a lane map when step i
+            # sees lane i and nothing of another lane: no carry to chain
+            # lane i into lane i+1, lane-shared consts, and every xs
+            # lane-shared or sliced by lane. The body then computes one
+            # lane from lane-shared values, and its ys stack by lane.
+            if nk or any(a is not None for a in consts) \
+                    or any(a not in (None, 0) for a in xs):
+                raise _Mix("scan iterates OVER the population axis with a "
+                           "carry, a lane-dependent const or an xs off "
+                           "axis 0 (step i can read lanes other than i)")
+            return [0] * len(eqn.outvars)
         xs_in = [a - 1 if a is not None else None for a in xs]
         body_out: List[Axis] = []
         for _ in range(nk + 1):
