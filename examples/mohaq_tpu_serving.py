@@ -41,9 +41,8 @@ The mesh-parity lane covers this path:
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
       PYTHONPATH=src python examples/mohaq_tpu_serving.py --sharded-demo
 
-``tools/check.sh`` chains the fast lane, the slow mesh lane, and the
-``benchmarks/run.py --quick`` lane (which records the ``search_sharded``
-throughput rows).
+``tools/check.sh`` chains the fast lane and the slow mesh lane. The
+search's speed is measured on the chip (``benchmarks/chip/``).
 """
 import argparse
 import json

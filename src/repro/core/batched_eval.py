@@ -14,8 +14,8 @@ generation 0) pay for hundreds of *serial* model evaluations. Because every
 menu precision is already expressed as a dynamic (scale, lo, hi) triple
 (``quantization.quant_triple`` — one jitted forward serves every allocation),
 an entire population batches for free: stack the per-layer triples of P
-candidates into a (P, L, 6) array and ``jax.vmap`` the quantized forward over
-the population axis. One jitted call then scores P candidates — the MxV
+candidates into a (P, L, 6) array and run the quantized forward with an
+explicit population axis. One jitted call then scores P candidates — the MxV
 einsums become single P-wide matmuls and the per-call dispatch overhead is
 paid once instead of P times.
 
@@ -24,9 +24,9 @@ compiles once per bucket, not once per population size.
 
 Population-axis layout: ``stack_qps`` produces the (P, L, 6) grid array —
 population lane x layer (in ``cfg.layer_names()`` order) x the six
-(w_scale, w_lo, w_hi, a_scale, a_lo, a_hi) floats. ``forward_population``
-keeps the P axis explicit end to end: P-batched MxV matmuls, one
-direction-fused recurrence scan per Bi-SRU layer, and (with
+(w_scale, w_lo, w_hi, a_scale, a_lo, a_hi) floats. The SRU's
+``forward_population`` keeps the P axis explicit end to end: P-batched MxV
+matmuls, one recurrence scan per Bi-SRU direction, and (with
 ``use_kernel=True``) a Pallas kernel whose grid is (P, B/bb, n/bn) so the
 population axis feeds the compute grid directly.
 
@@ -42,7 +42,7 @@ quantization, so every parity contract below is unchanged.
 
 One-dispatch-per-generation contract: with equal-shaped validation subsets
 (the standard case — they fold into the batch axis) a generation's whole
-evaluation — bank gather, fused Bi-SRU scan, frame-error reduction down to
+evaluation — bank gather, Bi-SRU scans, frame-error reduction down to
 per-(candidate, subset) integer error counts — is ONE jitted call, keyed by
 the existing population compile buckets. Only the O(P) count→percentage
 division and subset max stay on the host (kept in float64 numpy so error
@@ -477,18 +477,17 @@ class BatchedSRUEvaluator(PopulationEvaluator):
     ``models.sru.forward_population`` (and the input-layer u-bank hook) into
     the shared pipeline. Kept under its historical name — every PR-1..4
     contract (scalar parity, bank parity, mesh parity) is carried by the
-    generic base; this class only selects the SRU lowering.
+    generic base; this class only binds the SRU forward.
 
-    ``fused=True`` (default) runs the v2 explicit population-axis forward
-    (direction-fused scans); ``fused=False`` keeps the PR-1 vmap lowering
-    for benchmarking; ``use_kernel=True`` streams the recurrence through
-    the Pallas population kernel. All are bit-identical to the scalar path.
-    Quantized-weight banks need the explicit population axis, so they are
-    only enabled on the fused/kernel lanes.
+    Banks are gathered whenever a bank maker is wired (``use_banks=False``
+    keeps the requantize-per-lane lane, the reference the banks are checked
+    against); ``use_kernel=True`` streams the recurrence (and, with banks,
+    the MxV) through the Pallas population kernels. All are bit-identical
+    to the scalar path.
     """
 
     def __init__(self, cfg, val_subsets, make_qp: Callable[[Alloc], dict],
-                 use_kernel: bool = False, fused: bool = True,
+                 use_kernel: bool = False,
                  mesh=None, partition: str = "shard_map",
                  pop_axis: str = pop_sharding.POP_AXIS,
                  make_banks: Optional[Callable] = None,
@@ -499,19 +498,10 @@ class BatchedSRUEvaluator(PopulationEvaluator):
         from repro.models import sru
 
         self.cfg = cfg
-        if use_banks is None:       # banks need the explicit-population axis
-            maker = (make_packed_banks if bank_format == "packed"
-                     else make_banks)
-            use_banks = maker is not None and (fused or use_kernel)
-        if use_banks and bank_format == "f32" and make_banks is None:
-            raise ValueError("use_banks=True requires make_banks")
-        if use_banks and not (fused or use_kernel):
-            raise ValueError("banks require the fused or kernel lowering")
 
         def forward_pop(params, feats, qp_stack, banks):
             return sru.forward_population(params, cfg, feats, qp_stack,
-                                          use_kernel=use_kernel,
-                                          fused=fused, banks=banks)
+                                          use_kernel=use_kernel, banks=banks)
 
         extend = None
         if qp_tables is not None and cfg.input_dim != cfg.hidden:

@@ -136,7 +136,7 @@ def fake_quant_triple(x, scale, lo, hi, use_ste: bool = True):
 # Bit-parity contract: bank rows are built by ``fake_quant_triple`` with the
 # triples passed as *traced arrays* (never baked-in constants), i.e. the
 # exact per-element expression the on-the-fly paths execute — scalar
-# ``forward(qp=)`` and the fused population ``q_w`` vmap — so a gathered row
+# ``forward(qp=)`` and the population forward's ``q_w`` vmap — so a gathered row
 # is bitwise identical to requantizing on the fly (including the 16-bit
 # fixed-point grid, which ``quant_triple`` expresses as a plain
 # (scale, -32768, 32767) triple).
@@ -145,7 +145,7 @@ def fake_quant_triple(x, scale, lo, hi, use_ste: bool = True):
 # float round-trip ``x + (q - x)`` can differ from ``q`` in the last ulp at
 # clipped elements, and no eval lane takes gradients through weights (beacon
 # retraining quantizes via the separate ``qspec``/``ste_quantize_weight``
-# path). Every eval weight lane — scalar, fused requant, f32 bank, packed
+# path). Every eval weight lane — scalar, requant, f32 bank, packed
 # bank — therefore carries exactly ``clip(round(w/s), lo, hi) * s``, which
 # is what makes the packed-integer reconstruction below bit-exact.
 

@@ -182,38 +182,36 @@ class TrainedSRU:
             self._qp_tables = (w_t, a_t)
         return self._qp_tables
 
-    def batched_evaluator(self, fused: bool = True, mesh=None,
+    def batched_evaluator(self, mesh=None,
                           partition: str = "shard_map",
                           use_banks: Optional[bool] = None,
                           bank_format: str = "f32"
                           ) -> batched_eval.BatchedSRUEvaluator:
         """Lazily-built population evaluator (one jitted call scores a
         whole GA generation; compiled per population-size bucket).
-        ``fused=True`` is the v2 population-axis forward; ``fused=False``
-        keeps the PR-1 vmap lowering for comparison. ``use_banks`` controls
-        the quantized-weight-bank gather (default: on for the fused/kernel
-        lanes — ``use_banks=False`` keeps the requantize-per-lane v2 path
-        for benchmarking). ``bank_format='packed'`` gathers from packed-
-        integer banks instead of f32 stacks (bit-identical errors, >= 4x
-        less bank memory). ``mesh`` shards the population axis across its
-        "pop" device axis (``partition`` picks the shard_map or GSPMD
-        lowering, see distributed.pop_sharding)."""
+        ``use_banks`` controls the quantized-weight-bank gather (default:
+        on; ``use_banks=False`` keeps the requantize-per-lane lane, the
+        reference the banks are checked against). ``bank_format='packed'``
+        gathers from packed-integer banks instead of f32 stacks
+        (bit-identical errors, >= 4x less bank memory). ``mesh`` shards the
+        population axis across its "pop" device axis (``partition`` picks
+        the shard_map or GSPMD lowering, see distributed.pop_sharding)."""
         # Mesh hashes by devices + axis names, so equivalent meshes built
         # fresh per call share one compiled evaluator
         if use_banks is None:
-            use_banks = fused
-        key = (fused, use_banks, bank_format, mesh,
+            use_banks = True
+        key = (use_banks, bank_format, mesh,
                partition if mesh is not None else "")
         if key not in self._batched_eval:
             self._batched_eval[key] = batched_eval.BatchedSRUEvaluator(
-                self.cfg, self.val_subsets, self.qp_for, fused=fused,
+                self.cfg, self.val_subsets, self.qp_for,
                 mesh=mesh, partition=partition,
                 make_banks=self.make_banks, use_banks=use_banks,
                 qp_tables=self.qp_menu_tables(), bank_format=bank_format,
                 make_packed_banks=self.make_packed_banks)
         return self._batched_eval[key]
 
-    def val_error_batch(self, allocs, params=None, *, fused: bool = True,
+    def val_error_batch(self, allocs, params=None, *,
                         mesh=None, partition: str = "shard_map",
                         use_banks: Optional[bool] = None,
                         bank_format: str = "f32"):
@@ -221,14 +219,11 @@ class TrainedSRU:
         validation subsets for EVERY allocation in one call. Matches the
         scalar path exactly (integer error counts). ``params`` selects the
         full-precision parameter set (base or a retrained beacon's);
-        ``use_banks`` picks bank-gather vs requantize weight prep (banks by
-        default on the fused lane — bitwise identical, one bank build per
-        parameter set); ``mesh`` partitions the candidates across devices."""
+        ``use_banks`` picks bank-gather (the default — bitwise identical,
+        one bank build per parameter set) vs requantize weight prep;
+        ``mesh`` partitions the candidates across devices."""
         params = self.params if params is None else params
-        if bank_format == "packed" and use_banks is None:
-            use_banks = True
-        return self.batched_evaluator(fused=fused, mesh=mesh,
-                                      partition=partition,
+        return self.batched_evaluator(mesh=mesh, partition=partition,
                                       use_banks=use_banks,
                                       bank_format=bank_format
                                       ).errors(allocs, params)
@@ -436,7 +431,7 @@ def sru_contract_harness():
 
     def forward_pop(params, feats, qp_stack, banks=None):
         return sru.forward_population(params, cfg, feats, qp_stack,
-                                      fused=True, banks=banks)
+                                      banks=banks)
 
     def forward_decode(params, feats_lane, qp_stack, banks=None):
         # the serving hot path: feats_lane (P, T, m), one request chunk per

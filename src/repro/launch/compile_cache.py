@@ -1,9 +1,9 @@
 """Persistent XLA compilation cache for the repo's entry points.
 
 One place decides where compiled programs are kept, so every entry point
-(``chip_smoke.py``, ``benchmarks/run.py``, ``examples/*``) reuses the same
-cache. JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself; when it is set,
-nothing here overrides it. Otherwise the cache lives at ``<repo>/.jax_cache``
+(``chip_smoke.py``, ``examples/*``) reuses the same cache. JAX reads
+``JAX_COMPILATION_CACHE_DIR`` itself; when it is set, nothing here
+overrides it. Otherwise the cache lives at ``<repo>/.jax_cache``
 (listed in ``.gitignore``): a fixed path, because the directory is part of
 each entry's key and a moving one never hits.
 """

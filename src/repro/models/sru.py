@@ -326,78 +326,12 @@ def forward(params, cfg: SRUModelConfig, feats,
     return logits
 
 
-def forward_population(params, cfg: SRUModelConfig, feats, qp_stack,
-                       use_kernel: bool = False, fused: bool = True,
-                       banks=None):
-    """Population-parameterized forward: score P quantization candidates in
-    ONE jitted call.
-
-    ``qp_stack``: (P, L, 6) float32 — for each candidate (population lane)
-    and each layer in ``cfg.layer_names()`` order, the dynamic
-    (w_scale, w_lo, w_hi, a_scale, a_lo, a_hi) grids produced by
-    ``quant_triples_for``. Params and feats are closed over (broadcast, not
-    vmapped). Returns logits (P, B, T, n_outputs).
-
-    ``banks`` (optional): precomputed quantized-weight banks from
-    ``build_weight_banks`` for the SAME ``params``. When given, the fused
-    and kernel lanes *gather* each lane's quantized weight — row
-    ``menu_index_from_hi(w_hi)`` of the (|menu|, m, h) bank — instead of
-    fake-quantizing every weight tensor per lane per call. Only activations
-    (data-dependent) are still quantized on the fly. Bank rows store the
-    identical pure-grid fake-quant values the qp lane computes, so the
-    gathered lane is bitwise equal to the requantized one; all parity
-    contracts hold unchanged. Banks built with ``packed=True`` are detected
-    per node: the fused lane dequantizes the int containers once per layer
-    (bitwise equal to the f32 rows) and the kernel lane streams them into
-    ``kernels.ops.bank_qmm_pop``, which dequantizes in-kernel.
-
-    Three lowerings, all computing bit-identical per-element arithmetic to
-    the scalar ``forward(qp=)`` path (the GA's Pareto fronts are exact):
-
-    - ``fused=False, use_kernel=False``: the PR-1 reference — ``jax.vmap``
-      of the scalar forward over the grid axis (XLA batches the einsums and
-      scans itself). Kept for benchmarking/regression comparison; does not
-      support ``banks``.
-    - ``fused=True`` (default): explicit population axis. The MxV einsums
-      become P-batched matmuls and each Bi-SRU layer's two direction scans
-      are fused into ONE ``lax.scan`` over a stacked direction axis with a
-      small unroll — half the sequential while-loop steps of the vmap path.
-      Fusing a leading axis and unrolling never change per-element
-      arithmetic, so results stay bitwise equal to the scalar path.
-    - ``use_kernel=True``: same explicit population axis, but the recurrence
-      runs in the Pallas population-axis kernel (``kernels.ops.sru_scan_pop``)
-      whose grid is (P, B/bb, n/bn) — the population feeds the kernel grid
-      directly instead of vmapping over ``pallas_call``. In interpret mode
-      the kernel body mirrors the jnp scan step exactly. With ``banks`` the
-      MxV additionally runs in ``kernels.ops.bank_mxv_pop``, whose grid
-      reads the selected bank row directly via a scalar-prefetched index
-      (the bank is never expanded to P per-lane copies in memory).
-    """
-    if not fused and not use_kernel:
-        if banks is not None:
-            raise ValueError("banks require the fused or kernel lowering "
-                             "(the PR-1 vmap reference stays requantizing)")
-        if feats.ndim == 4:
-            raise ValueError("per-lane feats (P, B, T, m) require the fused "
-                             "or kernel lowering")
-        names = cfg.layer_names()
-
-        def one(qp_rows):                                  # (L, 6) per lane
-            qp = {n: qp_rows[i] for i, n in enumerate(names)}
-            return forward(params, cfg, feats, qp=qp)
-
-        return jax.vmap(one)(qp_stack)
-    return _forward_population_fused(params, cfg, feats, qp_stack,
-                                     use_kernel=use_kernel, banks=banks)
-
-
-# scan unroll for the fused population path: amortizes XLA while-loop
-# overhead without changing arithmetic (unrolling is exact)
-_POP_SCAN_UNROLL = 4
-# the banked dispatch re-tunes the unroll (measured best on the 2-core CPU
-# box at the compact eval shape); unrolling never changes per-element
-# arithmetic, so the two lanes stay bitwise interchangeable
-_BANK_SCAN_UNROLL = 8
+# lax.scan unroll of the population recurrence. Unrolling is exact (it
+# never changes per-element arithmetic), so the value only moves speed: 8
+# was the best measured on a 2-core CPU at the compact eval shape when the
+# banked lane was tuned, and it is the unroll the sru_timit chip cell's
+# program is compiled with — change it only against a chip measurement
+_SCAN_UNROLL = 8
 
 
 def extend_banks_u0(banks, cfg: SRUModelConfig, feats, a_trips):
@@ -438,27 +372,45 @@ def extend_banks_u0(banks, cfg: SRUModelConfig, feats, a_trips):
     return out
 
 
-def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
-                              use_kernel: bool = False, banks=None):
-    """Explicit population-axis forward (see ``forward_population``).
+def forward_population(params, cfg: SRUModelConfig, feats, qp_stack,
+                       use_kernel: bool = False, banks=None):
+    """Population-parameterized forward: score P quantization candidates in
+    ONE jitted call, each lane bitwise equal to the scalar ``forward(qp=)``
+    (the GA's Pareto fronts are exact).
 
-    feats (B, T, m) is broadcast to (P, B, T, m) — or passed pre-stacked as
-    (P, B, T, m) with one input per lane; per-lane weight/activation
-    grids come from qp_stack rows. Per-lane quantized weights are either
-    requantized on the fly (``banks=None``) or gathered from the
-    precomputed banks by menu index — bitwise identical, but the gather
-    replaces |layers| x P fake-quant passes per call with pure row selects.
-    Each Bi-SRU layer runs its two direction recurrences in one of three
-    forms, all with identical per-element arithmetic: the requant lane
-    fuses both directions into one scan over a stacked direction axis
-    (PR-2 lowering, byte-for-byte preserved as the benchmark baseline);
-    the banked lane runs one scan per direction with the backward stream
-    scanned ``reverse=True`` (no stack/flip copies, dead reset-gate output
-    elided, larger exact unroll); ``use_kernel=True`` streams through the
-    population-axis Pallas kernel (one call per direction,
-    grid (P, B/bb, n/bn)). Named scopes (``sru.bank_gather``,
-    ``sru.u0_bank``, ``sru.projection`` for every MxV, ``sru.scan``) carry
-    into the compiled program's op metadata."""
+    ``qp_stack``: (P, L, 6) float32 — for each candidate (population lane)
+    and each layer in ``cfg.layer_names()`` order, the dynamic
+    (w_scale, w_lo, w_hi, a_scale, a_lo, a_hi) grids produced by
+    ``quant_triples_for``. Params are closed over (broadcast, not vmapped).
+    ``feats`` (B, T, m) is one shared input scored under every lane's
+    grids, or (P, B, T, m) with one input per lane. Returns logits
+    (P, B, T, n_outputs).
+
+    ``banks`` (optional): precomputed quantized-weight banks from
+    ``build_weight_banks`` for the SAME ``params``. When given, each lane's
+    quantized weight is *gathered* — row ``menu_index_from_hi(w_hi)`` of
+    the (|menu|, m, h) bank — instead of fake-quantized per lane per call;
+    only activations (data-dependent) are still quantized on the fly. Bank
+    rows store the identical pure-grid values the requant lane computes,
+    so both are bitwise equal. Banks built with ``packed=True`` are
+    detected per node: the XLA lane dequantizes the int containers once per
+    layer (bitwise equal to the f32 rows) and the kernel lane streams them
+    into ``kernels.ops.bank_qmm_pop``, which dequantizes in-kernel. With
+    the L0 u-bank of ``extend_banks_u0``, the input layer's whole
+    quantize+MxV is one row gather per direction.
+
+    One lowering: the P axis is explicit end to end. The MxV einsums are
+    P-batched matmuls and each Bi-SRU direction runs its own recurrence,
+    the backward one scanned ``reverse=True`` (outputs come back aligned,
+    no time flips); the reset-gate output is elided when the highway skip
+    is statically inactive. ``use_kernel=True`` swaps the ``lax.scan`` for
+    the Pallas population-axis kernel (``kernels.ops.sru_scan_pop``, grid
+    (P, B/bb, n/bn), the backward stream time-flipped in and out), and with
+    ``banks`` the MxV for ``kernels.ops.bank_step``, which reads the
+    selected bank row in place via a scalar-prefetched index. Named scopes
+    (``sru.bank_gather``, ``sru.u0_bank``, ``sru.projection`` for every
+    MxV, ``sru.scan``) carry into the compiled program's op metadata.
+    """
     names = list(cfg.layer_names())
     li = {n: i for i, n in enumerate(names)}
     P = qp_stack.shape[0]
@@ -522,6 +474,34 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
             return u.reshape(xq.shape[:3] + (u.shape[-1],))
         return mxv(xq, lane_w(name, sub))
 
+    def recurrence(uw, uf, ur, v, b, reverse, highway):
+        """One direction over (P, B, T, n) streams -> (h, r), both aligned
+        with the input's time order; the scan elides ``r`` (None) when
+        ``highway`` is off."""
+        if use_kernel:
+            from repro.kernels import ops as kops
+            flip = (lambda a: a[:, :, ::-1]) if reverse else (lambda a: a)
+            with jax.named_scope("sru.scan"):
+                h, r = kops.sru_scan_pop(flip(uw), flip(uf), flip(ur),
+                                         v[0], v[1], b[0], b[1])
+            return flip(h), flip(r)
+
+        def step(c, t3):
+            uw_t, uf_t, ur_t = t3                            # (P,B,n)
+            f = jax.nn.sigmoid(uf_t + v[0] * c + b[0])
+            r = jax.nn.sigmoid(ur_t + v[1] * c + b[1])
+            c_new = f * c + (1.0 - f) * uw_t
+            return c_new, ((r * c_new, r) if highway else (r * c_new,))
+
+        tr = lambda a: a.transpose(2, 0, 1, 3)               # (T,P,B,n)
+        with jax.named_scope("sru.scan"):
+            _, out = jax.lax.scan(
+                step, jnp.zeros(uw.shape[:2] + (n,), jnp.float32),
+                (tr(uw), tr(uf), tr(ur)),
+                unroll=_SCAN_UNROLL, reverse=reverse)
+        h = out[0].transpose(1, 2, 0, 3)                     # (P,B,T,n)
+        return h, (out[1].transpose(1, 2, 0, 3) if highway else None)
+
     # feats (B, T, m): one shared input scored under P candidate grids
     # (the search substrate). feats (P, B, T, m): one input PER LANE —
     # the serving tier's population-axis-as-request-axis contract, where
@@ -540,7 +520,6 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
     x = dist_shard(x, "pop")
     for i in range(cfg.n_sru_layers):
         name = f"L{i}"
-        lp = params[name]
         # input-layer u-bank (see extend_banks_u0): L0's whole quantize+MxV
         # collapses to one row gather per direction; statically skipped when
         # the highway would need the quantized input, and for per-lane feats
@@ -554,113 +533,30 @@ def _forward_population_fused(params, cfg: SRUModelConfig, feats, qp_stack,
             xq = None
         else:
             xq = q_act(name, x)
-        if banks is not None and not use_kernel:
-            # banked dispatch: one scan per direction, the backward stream
-            # scanned with reverse=True — no direction stacking and no time
-            # flips (the reverse scan reads/writes positions in place, so
-            # outputs come back aligned). Identical per-element arithmetic
-            # to the stacked lane; the dead reset-gate output is elided when
-            # the highway skip is statically inactive.
-            highway = x.shape[-1] == n
-            hs = []
-            for key in ("fwd", "bwd"):
-                if use_u0:
-                    # re-anchor the lane axis here: with L0 gathered from
-                    # the u-bank the broadcast input (the usual anchor) is
-                    # dead code, so GSPMD must pick the partitioning up
-                    # from the gathered stream
-                    with jax.named_scope("sru.u0_bank"):
-                        u = jnp.take(banks[name][key]["U"], combo, axis=0)
-                    u = dist_shard(u, "pop")
-                else:
-                    u = mxv_layer(xq, name, key)             # (P,B,T,3n)
-                uw, uf, ur = u[..., :n], u[..., n:2 * n], u[..., 2 * n:]
-                v, b = banks[name][key]["v"], banks[name][key]["b"]
-
-                def step(c, t3, v=v, b=b):
-                    uw_t, uf_t, ur_t = t3                    # (P,B,n)
-                    f = jax.nn.sigmoid(uf_t + v[0] * c + b[0])
-                    r = jax.nn.sigmoid(ur_t + v[1] * c + b[1])
-                    c_new = f * c + (1.0 - f) * uw_t
-                    return c_new, ((r * c_new, r) if highway
-                                   else (r * c_new,))
-
-                tr = lambda a: a.transpose(2, 0, 1, 3)       # (T,P,B,n)
-                with jax.named_scope("sru.scan"):
-                    _, out = jax.lax.scan(
-                        step, jnp.zeros((P, x.shape[1], n), jnp.float32),
-                        (tr(uw), tr(uf), tr(ur)),
-                        unroll=_BANK_SCAN_UNROLL, reverse=(key == "bwd"))
-                h = out[0].transpose(1, 2, 0, 3)             # (P,B,T,n)
-                if highway:                                  # aligned: no flip
-                    h = h + (1.0 - out[1].transpose(1, 2, 0, 3)) * xq
-                hs.append(h)
-            x = jnp.concatenate(hs, axis=-1)
-            if i < cfg.n_sru_layers - 1:
-                pname = f"Pr{i + 1}"
-                x = mxv_layer(q_act(pname, x), pname)
-            continue
-
-        streams, vecs = [], []
+        highway = x.shape[-1] == n
+        hs = []
         for key in ("fwd", "bwd"):
-            dp = lp[key]
             if use_u0:
+                # re-anchor the lane axis here: with L0 gathered from the
+                # u-bank the broadcast input (the usual anchor) is dead
+                # code, so GSPMD must pick the partitioning up from the
+                # gathered stream
                 with jax.named_scope("sru.u0_bank"):
                     u = jnp.take(banks[name][key]["U"], combo, axis=0)
+                u = dist_shard(u, "pop")
             else:
                 u = mxv_layer(xq, name, key)                 # (P,B,T,3n)
             uw, uf, ur = u[..., :n], u[..., n:2 * n], u[..., 2 * n:]
-            if key == "bwd":
-                uw, uf, ur = uw[:, :, ::-1], uf[:, :, ::-1], ur[:, :, ::-1]
-            streams.append((uw, uf, ur))
             if banks is not None:             # 16-bit vectors pre-quantized
-                vecs.append((banks[name][key]["v"], banks[name][key]["b"]))
+                v, b = banks[name][key]["v"], banks[name][key]["b"]
             else:
-                vecs.append((Q.fixed_point_16(dp["v"]),
-                             Q.fixed_point_16(dp["b"])))
-
-        if use_kernel:
-            from repro.kernels import ops as kops
-            hs = []
-            for (uw, uf, ur), (v, b) in zip(streams, vecs):
-                with jax.named_scope("sru.scan"):
-                    h, r = kops.sru_scan_pop(uw, uf, ur, v[0], v[1], b[0],
-                                             b[1])
-                if x.shape[-1] == n:                         # highway skip
-                    hs_in = xq if len(hs) == 0 else xq[:, :, ::-1]
-                    h = h + (1.0 - r) * hs_in
-                hs.append(h)
-        else:
-            # both directions in ONE scan: stack on a leading dir axis
-            UW, UF, UR = (jnp.stack([s[k] for s in streams])
-                          for k in range(3))                 # (2,P,B,T,n)
-            VF, VR = (jnp.stack([v[0] for v, _ in vecs])[:, None, None],
-                      jnp.stack([v[1] for v, _ in vecs])[:, None, None])
-            BF, BR = (jnp.stack([b[0] for _, b in vecs])[:, None, None],
-                      jnp.stack([b[1] for _, b in vecs])[:, None, None])
-
-            def step(c, t3):
-                uw_t, uf_t, ur_t = t3                        # (2,P,B,n)
-                f = jax.nn.sigmoid(uf_t + VF * c + BF)
-                r = jax.nn.sigmoid(ur_t + VR * c + BR)
-                c_new = f * c + (1.0 - f) * uw_t
-                return c_new, (r * c_new, r)
-
-            c0 = jnp.zeros((2, P, x.shape[1], n), jnp.float32)
-            with jax.named_scope("sru.scan"):
-                _, (h, r) = jax.lax.scan(
-                    step, c0,
-                    (UW.transpose(3, 0, 1, 2, 4),
-                     UF.transpose(3, 0, 1, 2, 4),
-                     UR.transpose(3, 0, 1, 2, 4)),
-                    unroll=_POP_SCAN_UNROLL)
-            h = h.transpose(1, 2, 3, 0, 4)                   # (2,P,B,T,n)
-            r = r.transpose(1, 2, 3, 0, 4)
-            if x.shape[-1] == n:                             # highway skip
-                h = h.at[0].add((1.0 - r[0]) * xq)
-                h = h.at[1].add((1.0 - r[1]) * xq[:, :, ::-1])
-            hs = [h[0], h[1]]
-        x = jnp.concatenate([hs[0], hs[1][:, :, ::-1]], axis=-1)
+                v = Q.fixed_point_16(params[name][key]["v"])
+                b = Q.fixed_point_16(params[name][key]["b"])
+            h, r = recurrence(uw, uf, ur, v, b, key == "bwd", highway)
+            if highway:                                      # aligned: no flip
+                h = h + (1.0 - r) * xq
+            hs.append(h)
+        x = jnp.concatenate(hs, axis=-1)
         if i < cfg.n_sru_layers - 1:
             pname = f"Pr{i + 1}"
             x = mxv_layer(q_act(pname, x), pname)
@@ -690,9 +586,8 @@ def forward_decode_step(params, cfg: SRUModelConfig, feats, qp_stack,
     if feats.ndim != 3:
         raise ValueError(f"decode-step feats must be (P, T, m), got "
                          f"shape {feats.shape}")
-    logits = _forward_population_fused(params, cfg, feats[:, None],
-                                       qp_stack, use_kernel=use_kernel,
-                                       banks=banks)
+    logits = forward_population(params, cfg, feats[:, None], qp_stack,
+                                use_kernel=use_kernel, banks=banks)
     return logits[:, 0]
 
 

@@ -17,14 +17,14 @@ This package is the runtime that dispatches from it:
                 axis, so lane *i*'s menu index is request *i*'s allocation
                 (zero requantization, no per-allocation dispatch fan-out);
 - ``metrics``   per-request queue/compute/total latency and tokens/sec in a
-                structured log the bench harness consumes.
+                structured log (``ServingLog.summary``).
 
 The population-axis-as-request-axis contract: every per-lane quantity the
 search stacks for P *candidates* (qp grid rows, menu indices, bank gathers)
 is reused unchanged for P *requests* — the only new degree of freedom is
 per-lane input features (``feats`` of shape (P, T, m) instead of a
 broadcast (B, T, m)), which ``models.sru.forward_decode_step`` threads
-through the same fused/banked/kernel lowerings. Parity carries over: lane
+through the same banked/kernel lanes. Parity carries over: lane
 *i*'s served logits are bitwise equal to the scalar ``forward(qp=...)``
 path on the same chunk.
 """

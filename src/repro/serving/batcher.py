@@ -22,7 +22,8 @@ bitwise equal to the scalar ``forward(qp=)`` path on the same frames.
 ``SerialGroupBatcher`` is the measured counterfactual: the same engine
 and step cadence, but each step fans out one dispatch PER ALLOCATION
 GROUP — exactly what a naive "one compiled model per operating point"
-server does. The bench gate holds continuous batching against it.
+server does: same logits, one dispatch per live allocation per step
+(``tests/test_serving.py``).
 """
 from __future__ import annotations
 
@@ -251,8 +252,8 @@ class SerialGroupBatcher(ContinuousBatcher):
     step issues one dispatch PER ALLOCATION present in the batch, the way
     a server with one compiled model per operating point must. On a mixed
     front this multiplies the per-step fixed costs (dispatch, scan
-    overhead, partially-filled buckets) by the number of live allocations;
-    the bench gate measures exactly that gap.
+    overhead, partially-filled buckets) by the number of live
+    allocations.
     """
 
     def _dispatch_groups(self) -> List[List[_Flight]]:

@@ -3,9 +3,8 @@
 The batcher stamps wall-clock times at enqueue / first-dispatch / retire
 and per-step compute spans; this module turns those stamps into the
 per-request latency decomposition (queue vs compute vs total) and the
-aggregate throughput/percentile rows the bench harness consumes
-(``benchmarks/run.py::serving_family`` writes them into
-``BENCH_serving.json``). Pure bookkeeping — nothing here touches jax, so
+aggregate throughput/percentile summary that ``chip_smoke.py`` and the
+serving examples print. Pure bookkeeping — nothing here touches jax, so
 none of it can leak host side effects into the jitted step.
 """
 from __future__ import annotations
@@ -69,7 +68,7 @@ class StepRecord:
 
 @dataclass
 class ServingLog:
-    """Accumulates request + step records and reduces them to bench rows."""
+    """Accumulates request + step records and reduces them to a summary."""
     requests: Dict[int, RequestRecord] = field(default_factory=dict)
     steps: List[StepRecord] = field(default_factory=list)
 
@@ -127,7 +126,7 @@ class ServingLog:
         }
 
     def summary(self) -> Dict[str, object]:
-        """Everything the bench row needs, JSON-ready."""
+        """Counts, throughput and latency percentiles, JSON-ready."""
         out: Dict[str, object] = {
             "n_completed": len(self.completed()),
             "n_shed": self.shed_count(),

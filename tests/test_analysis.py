@@ -551,7 +551,7 @@ def test_contracts_fail_on_requantizing_forward(sru_harness):
 
     def requantizing_forward(params, feats, qp_stack, banks=None):
         return sru.forward_population(params, cfg, feats, qp_stack,
-                                      fused=True, banks=None)
+                                      banks=None)
 
     bad = dataclasses.replace(h, forward_pop=requantizing_forward,
                               supports_requant=False)
@@ -580,7 +580,7 @@ def test_contracts_fail_on_f32_leak_in_packed_lane(sru_harness):
         if banks is not None and isinstance(banks["L0"]["fwd"]["W"], dict):
             banks = f32_banks
         return sru.forward_population(params, cfg, feats, qp_stack,
-                                      fused=True, banks=banks)
+                                      banks=banks)
 
     bad = dataclasses.replace(h, forward_pop=leaky_forward)
     findings = check_harness(bad)
@@ -605,7 +605,7 @@ def test_c5_fails_on_lane_mixing_forward(sru_harness):
 
     def lane_flipping_forward(params, feats, qp_stack, banks=None):
         out = sru.forward_population(params, cfg, feats, qp_stack,
-                                     fused=True, banks=banks)
+                                     banks=banks)
         return jax.tree_util.tree_map(lambda t: t[::-1], out)
 
     bad = dataclasses.replace(h, forward_pop=lane_flipping_forward,
@@ -631,7 +631,7 @@ def test_c5_fails_on_cross_lane_normalization(sru_harness):
 
     def mean_mixing_forward(params, feats, qp_stack, banks=None):
         out = sru.forward_population(params, cfg, feats, qp_stack,
-                                     fused=True, banks=banks)
+                                     banks=banks)
         return out - out.mean(axis=0, keepdims=True)
 
     bad = dataclasses.replace(h, forward_pop=mean_mixing_forward,

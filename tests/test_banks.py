@@ -45,7 +45,7 @@ def _random_allocs(problem, n, seed=0):
 # eval lanes never take weight gradients, and the STE wrapper's float
 # round-trip ``x + (q - x)`` can differ from ``q`` in the last ulp at
 # clipped elements — pure ``q`` is what every eval weight lane (scalar qp,
-# fused requant, f32 bank, packed bank) computes
+# requant, f32 bank, packed bank) computes
 _fq = jax.jit(lambda x, s, lo, hi: Q.fake_quant_triple(x, s, lo, hi,
                                                        use_ste=False))
 
@@ -215,7 +215,7 @@ class TestBankKernel:
 
     def test_kernel_lane_with_banks(self, trained, problem, banks):
         """use_kernel=True with banks routes the MxV through the bank
-        kernel and stays on the fused lane's numbers."""
+        kernel and stays on the XLA lane's numbers."""
         allocs = _random_allocs(problem, 3, seed=11)
         qp_stack = jnp.asarray(BE.stack_qps(
             [trained.qp_for(a) for a in allocs],

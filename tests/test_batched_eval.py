@@ -61,26 +61,24 @@ class TestErrorParity:
         assert scalar == batched
 
     def test_all_population_lowerings_bit_identical(self, trained, problem):
-        """The three forward_population lowerings — PR-1 vmap, v2 fused
-        (direction-fused scan, population-batched matmuls) and the Pallas
-        population-axis kernel — all reproduce the scalar error counts."""
+        """The population forward — its XLA scan and its Pallas
+        population-axis kernel lane — reproduces the scalar error counts."""
         import jax.numpy as jnp
         from repro.models import sru
 
         allocs = _random_allocs(problem, 5, seed=9)
         scalar = [trained.val_error(a) for a in allocs]
-        assert trained.val_error_batch(allocs, fused=False) == scalar
-        assert trained.val_error_batch(allocs, fused=True) == scalar
-        # kernel path (interpret mode): logits must match the fused path
+        assert trained.val_error_batch(allocs) == scalar
+        # kernel path (interpret mode): logits must match the XLA scan
         qp_stack = jnp.asarray(BE.stack_qps(
             [trained.qp_for(a) for a in allocs],
             list(trained.cfg.layer_names())))
         feats = trained.val_subsets[0][0]
-        l_fused = sru.forward_population(trained.params, trained.cfg, feats,
-                                         qp_stack, fused=True)
+        l_xla = sru.forward_population(trained.params, trained.cfg, feats,
+                                       qp_stack)
         l_kern = sru.forward_population(trained.params, trained.cfg, feats,
                                         qp_stack, use_kernel=True)
-        np.testing.assert_allclose(np.asarray(l_kern), np.asarray(l_fused),
+        np.testing.assert_allclose(np.asarray(l_kern), np.asarray(l_xla),
                                    rtol=1e-5, atol=1e-5)
 
     def test_evaluate_population_matches_evaluate(self, problem):
@@ -135,19 +133,19 @@ class TestSearchParity:
         assert key(rs) == key(rb)
 
     def test_memoized_search_matches_pr1_evaluator(self, trained):
-        """The memoized v2 pipeline returns a bit-identical Pareto front to
-        PR 1's vmap evaluator, and the run logs a consistent cache-hit
-        count (requested = unique evals + genome cache hits)."""
+        """The memoized banked pipeline returns a bit-identical Pareto front
+        to the requantize-per-lane evaluator, and the run logs a consistent
+        cache-hit count (requested = unique evals + genome cache hits)."""
         kw = dict(n_generations=5, pop_size=6, initial_pop_size=10, seed=7)
-        prob_v1 = X.build_problem(trained, X.BITFUSION, ("error", "speedup"))
-        prob_v1.batch_error_fn = \
-            lambda allocs: trained.val_error_batch(allocs, fused=False)
-        prob_v1.error_memo = {}
-        prob_v2 = X.build_problem(trained, X.BITFUSION, ("error", "speedup"))
-        prob_v2.error_memo = {}
+        prob_rq = X.build_problem(trained, X.BITFUSION, ("error", "speedup"))
+        prob_rq.batch_error_fn = \
+            lambda allocs: trained.val_error_batch(allocs, use_banks=False)
+        prob_rq.error_memo = {}
+        prob_bk = X.build_problem(trained, X.BITFUSION, ("error", "speedup"))
+        prob_bk.error_memo = {}
         logs = []
-        r1 = run_search(prob_v1, **kw)
-        r2 = run_search(prob_v2, log=logs.append, **kw)
+        r1 = run_search(prob_rq, **kw)
+        r2 = run_search(prob_bk, log=logs.append, **kw)
         key = lambda res: sorted((tuple(i.genome.tolist()),
                                   tuple(i.objectives.tolist()),
                                   float(i.violation)) for i in res.pareto)

@@ -167,7 +167,7 @@ class TestPackedForwardParity:
     def test_packed_kernel_lane_matches_fused(self, trained, problem,
                                               banks_packed):
         """use_kernel=True routes the packed MxV through ``bank_qmm_pop``
-        (in-kernel dequant); float tolerance vs the fused packed lane."""
+        (in-kernel dequant); float tolerance vs the XLA packed lane."""
         allocs = _random_allocs(problem, 3, seed=11)
         qp_stack = jnp.asarray(BE.stack_qps(
             [trained.qp_for(a) for a in allocs],

@@ -286,12 +286,14 @@ class TestDecodeStepAndKernel:
                 jnp.zeros((2, 1, 8, artifact.cfg.input_dim)),
                 jnp.asarray(artifact.qp[:2]), banks=artifact.banks)
 
-    def test_vmap_path_rejects_per_lane_feats(self, trained):
-        with pytest.raises(ValueError, match="per-lane feats"):
+    def test_per_lane_feats_must_match_population(self, trained):
+        """Per-lane feats carry one input per population lane, so their
+        lead axis must be the qp stack's P."""
+        with pytest.raises(ValueError, match="lead axis"):
             sru.forward_population(
                 trained.params, trained.cfg,
-                jnp.zeros((2, 1, 4, trained.cfg.input_dim)),
-                jnp.zeros((2, len(trained.layer_names), 6)), fused=False)
+                jnp.zeros((3, 1, 4, trained.cfg.input_dim)),
+                jnp.zeros((2, len(trained.layer_names), 6)))
 
     def test_engine_step_is_provably_lane_independent(self, engine):
         """The pad-lane/neighbor-isolation argument in the batcher's

@@ -1,7 +1,7 @@
 """Mesh-parity lane for the sharded population evaluator.
 
 Contract (PRs 1-3): error counts are integers and every evaluator lowering
-— scalar, batched, population-axis fused, and now mesh-sharded — must agree
+— scalar, batched, population-axis, and now mesh-sharded — must agree
 EXACTLY, so Pareto fronts compare with ``==``, never with tolerances.
 
 Fast tests exercise the sharding machinery in-process on a 1-device "pop"

@@ -24,8 +24,10 @@
 #                    (the mesh-parity tests spawn their own subprocess with
 #                    the XLA flag; exporting it here also runs the
 #                    in-process suites against 8 virtual devices)
-#   3. benchmarks  — the --quick benchmark lane: paper tables, kernels,
-#                    search-throughput regression gate, sharded rows
+#   3. benchmarks  — the paper's Tables 1-8 recomputed from
+#                    benchmarks/paper_tables.py (untimed CSV rows; the
+#                    search's speed is measured on the chip by
+#                    benchmarks/chip/, never here)
 #
 #   0. api smoke   — import + public-name check of the repro.core.api
 #                    SearchTarget/SearchSession surface and the platform
@@ -54,13 +56,11 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-# Persistent XLA compilation cache: the search/bench pipelines recompile the
-# same per-bucket evaluators every run; caching them cuts the first-call
-# column (benchmarks/run.py reports first-call vs steady-state separately —
-# the regression gates read steady-state only, so a cold cache can never
-# flip a gate). Override JAX_COMPILATION_CACHE_DIR to relocate, or set it
-# to the empty string to disable (the `-` expansion keeps an explicitly
-# empty value, unlike `:-`).
+# Persistent XLA compilation cache: the search pipelines recompile the same
+# per-bucket evaluators every run; caching them cuts the compile time of
+# every stage after the first. Override JAX_COMPILATION_CACHE_DIR to
+# relocate, or set it to the empty string to disable (the `-` expansion
+# keeps an explicitly empty value, unlike `:-`).
 export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR-$PWD/.jax_cache}"
 if [ -n "$JAX_COMPILATION_CACHE_DIR" ]; then
   export JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="${JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS:-0.2}"
@@ -229,8 +229,8 @@ run_slow() {
 }
 
 run_bench() {
-  echo "== benchmarks: python -m benchmarks.run --quick =="
-  python -m benchmarks.run --quick
+  echo "== benchmarks: python -m benchmarks.run (paper Tables 1-8) =="
+  python -m benchmarks.run
 }
 
 case "$stage" in
